@@ -2,6 +2,10 @@
 the FLOP argument (the einsum path burns O(T·E·C·d) in one-hot matmuls;
 the Roomy path doesn't). The production-scale collective comparison lives
 in the dry-run (§Perf); this is the runnable small-scale twin.
+
+The child process runs on 8 fake CPU devices (``JAX_PLATFORMS=cpu``), so
+it never competes with its parent for an accelerator; its rows time the
+CPU backend.  A failed child raises.
 """
 from __future__ import annotations
 
@@ -36,19 +40,19 @@ for name, f in (("einsum", f_e), ("roomy", f_r)):
 """
     import os
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=300)
-    rows = []
     if proc.returncode != 0:
-        return [("moe_dispatch_bench", 0.0,
-                 f"FAILED: {proc.stderr[-200:]}")]
+        raise RuntimeError(f"moe dispatch child failed: {proc.stderr[-2000:]}")
+    rows = []
     for line in proc.stdout.splitlines():
         if line.startswith("RESULT"):
             _, name, us = line.split()
             rows.append((f"moe_dispatch_{name}", float(us),
-                         "tokens=1024 experts=8 top2 (8 fake devices)"))
+                         "tokens=1024 experts=8 top2 (8 fake CPU devices)"))
     return rows

@@ -8,6 +8,9 @@ Prints ``name,us_per_call,derived`` CSV (the harness contract). Sections:
   lm           per-family train/decode step wall times (smoke configs)
   serve        distance-oracle serving tier: QPS + p50/p99 under
                concurrent closed-loop clients at a starved LRU budget
+
+A section that raises prints ``<section>_FAILED`` and the others still
+run, but the exit code is then non-zero.
 """
 from __future__ import annotations
 
@@ -17,7 +20,7 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=("constructs", "pancake", "bfs",
                                        "disk", "moe", "lm", "serve"))
@@ -36,7 +39,10 @@ def main() -> None:
                          "record: {section: [{name, us_per_call, derived}]})")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
     from . import constructs, disk_tier, lm_step, moe_dispatch, pancake
+    compile_cache.enable_compile_cache()
 
     def bench_bfs_section():
         # Imported lazily: bfs pulls in examples/cayley_bfs.py via a path
@@ -84,8 +90,8 @@ def main() -> None:
     if args.json:
         with open(args.json, "w") as f:
             json.dump(record, f, indent=2)
-    return None
+    return 1 if record["errors"] else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

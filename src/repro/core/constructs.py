@@ -231,26 +231,54 @@ def _bfs_level_reference(cur: RL.RoomyList, all_lst: RL.RoomyList,
     return nxt, all2, overflow | ov2
 
 
+IMPLICIT_BLOCK = 1 << 20     # states expanded per block of an implicit level
+
+
 def _implicit_level(data, *, n_states: int, neighbor_fn: Callable,
-                    impl: str, fused: bool = True):
+                    impl: str, fused: bool = True,
+                    block: int = IMPLICIT_BLOCK):
     """One implicit-BFS level over the packed 2-bit array: mark every
     neighbor of a CUR state NEXT-if-UNSEEN (the delayed-update batch — a
     masked scatter, duplicates and visited states absorb silently), then
-    rotate CUR→DONE / NEXT→CUR and count the new frontier.  With
-    ``fused=True`` the mark scatter and the LUT rotate+count run as ONE
-    kernel over the packed words (kernels/bitpack.py
-    bitpack_mark_rotate_count) — one HBM read-write traversal of the
-    array per level instead of two, the Tier J twin of the disk pass
-    planner's fused level.  No sort of any kind either way."""
-    cap = data.shape[0] * BA.FIELDS_PER_WORD
-    vals = BA.unpack_values(data)[:n_states]
-    cur = vals == BA.CUR
-    nbr = jax.vmap(neighbor_fn)(jnp.arange(n_states, dtype=jnp.int32))
-    tgt = jnp.where(cur[:, None], nbr.astype(jnp.int32), cap).reshape(-1)
-    if fused:
-        return BA.mark_rotate_count(data, tgt, n_states, impl=impl)
-    data = BA.mark_packed(data, tgt, impl=impl)
-    return BA.rotate_count(data, n_states, impl=impl)
+    rotate CUR→DONE / NEXT→CUR and count the new frontier.  No sort of any
+    kind.
+
+    The index space is expanded in blocks of at most ``block`` states, so
+    the level's peak memory is O(block), not O(n_states); each block's
+    marks are applied before the next block expands.  Marks never touch a
+    CUR field, so every block reads CUR from the level's input.  With
+    ``fused=True`` the last block's marks and the LUT rotate+count run as
+    ONE kernel over the packed words (kernels/bitpack.py
+    bitpack_mark_rotate_count), the Tier J twin of the disk pass
+    planner's fused level; ``fused=False`` marks every block, then
+    rotates and counts in a pass of its own."""
+    f = BA.FIELDS_PER_WORD
+    cap = data.shape[0] * f
+    nblk = -(-n_states // block)
+    bs = -(-n_states // (nblk * f)) * f          # states per block
+    src = jnp.pad(data, (0, nblk * bs // f - data.shape[0]))
+    shifts = (2 * jnp.arange(f, dtype=jnp.uint32))[:, None]
+    # Block-local state ids in field-major order: row j holds field j of
+    # every word, so the CUR test reads the words without an unpack.
+    local = (f * jnp.arange(bs // f, dtype=jnp.int32)[None, :]
+             + jnp.arange(f, dtype=jnp.int32)[:, None]).reshape(-1)
+
+    def targets(b):
+        words = jax.lax.dynamic_slice(src, (b * (bs // f),), (bs // f,))
+        cur = (((words[None, :] >> shifts) & 3) == BA.CUR).reshape(-1)
+        ids = b * bs + local
+        nbr = jax.vmap(neighbor_fn, out_axes=1)(jnp.minimum(ids, n_states - 1))
+        live = cur & (ids < n_states)
+        return jnp.where(live[None, :], nbr.astype(jnp.int32), cap).reshape(-1)
+
+    def mark(b, d):
+        return BA.mark_packed(d, targets(b), impl=impl)
+
+    if not fused:
+        data = jax.lax.fori_loop(0, nblk, mark, data)
+        return BA.rotate_count(data, n_states, impl=impl)
+    data = jax.lax.fori_loop(0, nblk - 1, mark, data)
+    return BA.mark_rotate_count(data, targets(nblk - 1), n_states, impl=impl)
 
 
 def implicit_bfs(
@@ -266,10 +294,11 @@ def implicit_bfs(
     of ``disk.implicit_bfs``.
 
     neighbor_fn(i int32) -> (fanout,) int32 neighbor indices; it is vmapped
-    over the whole index space each level — the static-shape adaptation of
-    "expand the CUR states" (non-CUR rows are masked out of the mark), so a
-    level costs O(n_states) regardless of frontier size but needs no
-    frontier list, no sorting and no duplicate elimination.
+    over the whole index space each level, one block of states at a time
+    (_implicit_level) — the static-shape adaptation of "expand the CUR
+    states" (non-CUR rows are masked out of the mark), so a level costs
+    O(n_states) regardless of frontier size but needs no frontier list, no
+    sorting and no duplicate elimination.
 
     Returns (level_sizes, bits: RoomyBitArray) — all reached states end
     DONE in ``bits``.  ``fused=False`` keeps the two-kernel reference
@@ -283,7 +312,7 @@ def implicit_bfs(
         (BA.unpack_values(data)[:n_states] == BA.CUR).astype(jnp.int32)))]
     step = jax.jit(functools.partial(_implicit_level, n_states=n_states,
                                      neighbor_fn=neighbor_fn, impl=impl,
-                                     fused=fused))
+                                     fused=fused, block=IMPLICIT_BLOCK))
     for _ in range(max_levels):
         with obs.span("bfs.level", level=len(level_sizes), tier="j",
                       engine="implicit"):

@@ -143,10 +143,22 @@ def _divmod_u64(hi: jax.Array, lo: jax.Array, i: int):
     return q_hi, q_lo, rem.astype(jnp.uint32)
 
 
+def _pick(cols, s):
+    """cols[s] per row, for a traced index ``s`` — a chain of selects over
+    the columns instead of a gather."""
+    out = cols[0]
+    for k in range(1, len(cols)):
+        out = jnp.where(s == k, cols[k], out)
+    return out
+
+
 def unrank_jnp(n: int, rank_rows: jax.Array) -> jax.Array:
     """Batched unrank: (m, rank_width(n)) uint32 rows → (m, n) int32 perms.
 
     Accepts width-1 rows for n ≤ 12 and width-2 (hi, lo) rows for any n.
+    The permutation is held as n columns of shape (m,), and each swap is a
+    select per column, so the batch axis stays the minor (lane) axis and
+    no gather or scatter is emitted.
     """
     assert 1 <= n <= MAX_N
     rank_rows = rank_rows.astype(jnp.uint32)
@@ -155,41 +167,37 @@ def unrank_jnp(n: int, rank_rows: jax.Array) -> jax.Array:
         lo = rank_rows[:, 0]
     else:
         hi, lo = rank_rows[:, 0], rank_rows[:, 1]
-    m = lo.shape[0]
-    pi = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (m, n))
-    rows = jnp.arange(m)
+    cols = [jnp.full(lo.shape, k, jnp.int32) for k in range(n)]
     for i in range(n, 0, -1):
         hi, lo, s = _divmod_u64(hi, lo, i)
         s = s.astype(jnp.int32)
-        a = pi[:, i - 1]
-        b = pi[rows, s]
-        pi = pi.at[:, i - 1].set(b)
-        pi = pi.at[rows, s].set(a)
-    return pi
+        a, b = cols[i - 1], _pick(cols[:i], s)      # swap pi[i-1] ↔ pi[s]
+        cols = ([jnp.where(s == k, a, c) for k, c in enumerate(cols[:i - 1])]
+                + [b] + cols[i:])
+    return jnp.stack(cols, axis=1)
 
 
 def rank_jnp(perms: jax.Array, width: int | None = None) -> jax.Array:
     """Batched rank: (m, n) perms → (m, width) uint32 rank rows.
 
-    width defaults to rank_width(n); word 0 is the high word.
+    width defaults to rank_width(n); word 0 is the high word.  Columns and
+    selects as in unrank_jnp; the inverse permutation is built by
+    comparison instead of an argsort.
     """
     pi = perms.astype(jnp.int32)
     m, n = pi.shape
     assert 1 <= n <= MAX_N
     width = width or rank_width(n)
-    pinv = jnp.argsort(pi, axis=1).astype(jnp.int32)
-    rows = jnp.arange(m)
+    cols = [pi[:, k] for k in range(n)]
+    inv = [sum(jnp.where(c == v, k, 0) for k, c in enumerate(cols))
+           for v in range(n)]
     s_seq = []
     for i in range(n, 1, -1):
-        s = pi[:, i - 1]
-        j = pinv[:, i - 1]
-        pj = pi[rows, j]
-        pi = pi.at[:, i - 1].set(pj)
-        pi = pi.at[rows, j].set(s)
-        t = pinv[rows, s]
-        u = pinv[:, i - 1]
-        pinv = pinv.at[rows, s].set(u)
-        pinv = pinv.at[:, i - 1].set(t)
+        # Value i-1 moves to its home slot i-1 and the value it displaces,
+        # s, to slot j; only slots below i-1 are read again.
+        s, j = cols[i - 1], inv[i - 1]
+        cols = [jnp.where(j == k, s, c) for k, c in enumerate(cols[:i - 1])]
+        inv = [jnp.where(s == v, j, c) for v, c in enumerate(inv[:i - 1])]
         s_seq.append(s)
     hi = jnp.zeros((m,), jnp.uint32)
     lo = jnp.zeros((m,), jnp.uint32)

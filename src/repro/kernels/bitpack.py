@@ -15,10 +15,10 @@ is exactly VPU-shaped work:
                         whose 2-bit field must become ``mark`` iff it
                         currently holds ``only_if`` (the OR-style visited
                         test of the BFS — marks on non-UNSEEN states are
-                        absorbed).  Sequential read-modify-write per op,
-                        same trash-row convention as bucket_scatter.py; the
-                        packed table must fit VMEM (callers tile by shard,
-                        which the Roomy layout already provides).
+                        absorbed).  Sequential read-modify-write per op
+                        on a VMEM-resident table; dropped ops go to a trash
+                        word.  The table must fit VMEM: on v5e (128 MiB)
+                        one of up to 120 MiB compiles, pancake n=12.
 
   bitpack_mark_rotate_count
                         the two fused into ONE kernel — the whole per-level
@@ -37,8 +37,11 @@ is exactly VPU-shaped work:
                         paged_decode.py idiom) so each grid step streams
                         exactly one page of packed words into VMEM.
 
-All have pure-jnp oracles in ref.py and interpret-mode CPU validation in
-tests/test_kernels.py; ops.py hosts the dispatching wrappers.
+The first three share one table layout (table_layout): the packed words
+lane-dense as (rows, 128) uint32, so a word costs 4 bytes of VMEM and not
+a 512-byte row.  All have pure-jnp oracles in ref.py and interpret-mode
+CPU validation in tests/test_kernels.py; ops.py hosts the dispatching
+wrappers.
 """
 from __future__ import annotations
 
@@ -50,13 +53,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams; keep both working.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 FIELDS_PER_WORD = 16
 LANES = 128
-DEFAULT_BW = 8           # words-per-block rows (uint32 tile is (8, 128))
-DEFAULT_BM = 256         # scatter ops per block
+ROW_BLOCK = 512          # table rows per block (256 KiB of uint32)
+DEFAULT_BM = 2048        # scatter ops per SMEM block
+VMEM_HEADROOM = 16 << 20  # scoped VMEM beyond the table: rotate temporaries
 
 
 def make_lut(table) -> int:
@@ -66,19 +67,49 @@ def make_lut(table) -> int:
     return sum(int(v) << (2 * i) for i, v in enumerate(table))
 
 
+# ------------------------------------------------------- table layout
+
+def table_layout(n_words: int, row_block: int = ROW_BLOCK):
+    """``(rows, rb)`` of the lane-dense ``(rows, 128)`` uint32 table all
+    three array-pass kernels share.  It holds ``n_words + 1`` words — word
+    ``n_words`` is the trash word that absorbs dropped marks, so it lives
+    in the padding — rounded up to whole blocks of ``rb`` rows, ``rb`` a
+    multiple of the (8, 128) uint32 tile."""
+    need = -(-(n_words + 1) // LANES)
+    rb = min(row_block, -(-need // 8) * 8)
+    return -(-need // rb) * rb, rb
+
+
+def _to_table(packed: jax.Array, rows: int) -> jax.Array:
+    pad = rows * LANES - packed.shape[0]
+    return jnp.pad(packed.astype(jnp.uint32), (0, pad)).reshape(rows, LANES)
+
+
+def _lut_rotate(w, first_word, n_words: int, lut: int, count_val: int):
+    """Map the 16 fields of every word of an ``(r, 128)`` block through the
+    LUT; count mapped fields equal to ``count_val`` among the words whose
+    flat index (``first_word`` + position) is below ``n_words``, so table
+    padding — the trash word included — never counts."""
+    acc = jnp.zeros_like(w)
+    hits = jnp.zeros(w.shape, jnp.int32)
+    for j in range(FIELDS_PER_WORD):
+        nf = (jnp.uint32(lut) >> (2 * ((w >> (2 * j)) & 3))) & 3
+        acc = acc | (nf << (2 * j))
+        hits = hits + (nf == count_val).astype(jnp.int32)
+    flat = (first_word
+            + jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, w.shape, 1))
+    return acc, jnp.sum(jnp.where(flat < n_words, hits, 0))
+
+
 # ---------------------------------------------------------- lut + count
 
-def _lut_count_kernel(p_ref, o_ref, cnt_ref, *, lut: int, count_val: int):
+def _lut_count_kernel(p_ref, o_ref, cnt_ref, *, lut: int, count_val: int,
+                      n_words: int):
     blk = pl.program_id(0)
-    w = p_ref[...]
-    acc = jnp.zeros_like(w)
-    total = jnp.zeros((), jnp.int32)
-    for j in range(FIELDS_PER_WORD):
-        f = (w >> (2 * j)) & 3
-        nf = (jnp.uint32(lut) >> (2 * f)) & 3
-        acc = acc | (nf << (2 * j))
-        total = total + jnp.sum((nf == count_val).astype(jnp.int32))
-    o_ref[...] = acc
+    new, total = _lut_rotate(p_ref[...], blk * (p_ref.shape[0] * LANES),
+                             n_words, lut, count_val)
+    o_ref[...] = new
 
     @pl.when(blk == 0)
     def _init():
@@ -92,97 +123,156 @@ def bitpack_lut_count(
     lut: int,                # make_lut(...) scalar (static)
     count_val: int,          # field value to count after mapping (static)
     *,
-    block_w: int = DEFAULT_BW,
+    block_w: int = ROW_BLOCK,
     interpret: bool = False,
 ):
     """Map every 2-bit field through ``lut`` and count resulting fields ==
     ``count_val``.  Returns (new_packed (W,) uint32, count () int32).
 
-    Padding note: the grid pads W up to whole (block_w, 128) tiles with
-    zero words; that tile padding is corrected below, so the count covers
-    exactly the W·16 fields of the input words.  Callers owning fewer than
-    W·16 logical elements correct for THEIR tail fields themselves (see
+    The count covers exactly the W·16 fields of the input words (table
+    padding is masked out in the kernel).  Callers owning fewer than W·16
+    logical elements correct for THEIR tail fields themselves (see
     core/bitarray.py rotate_count).
     """
     w = packed.shape[0]
-    rows = -(-w // LANES)
-    rows_pad = -(-rows // block_w) * block_w
-    p2 = jnp.zeros((rows_pad * LANES,), jnp.uint32).at[:w].set(packed)
-    p2 = p2.reshape(rows_pad, LANES)
-
+    rows, rb = table_layout(w, block_w)
     kernel = functools.partial(_lut_count_kernel, lut=lut,
-                               count_val=count_val)
+                               count_val=count_val, n_words=w)
     out, cnt = pl.pallas_call(
         kernel,
-        grid=(rows_pad // block_w,),
-        in_specs=[pl.BlockSpec((block_w, LANES), lambda i: (i, 0))],
+        grid=(rows // rb,),
+        in_specs=[pl.BlockSpec((rb, LANES), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((block_w, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((rb, LANES), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows_pad, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="roomy_bitpack_lut_count",
-    )(p2)
-    pad_fields = (rows_pad * LANES - w) * FIELDS_PER_WORD
-    lut0 = lut & 3
-    cnt_corr = cnt[0, 0] - (pad_fields if lut0 == count_val else 0)
-    return out.reshape(-1)[:w], cnt_corr
+    )(_to_table(packed, rows))
+    return out.reshape(-1)[:w], cnt[0, 0]
 
 
-# -------------------------------------------------------- scatter mark
+# ------------------------------------- scatter mark (+ rotate + count)
 
-def _scatter_mark_kernel(idx_ref, tab_ref, out_ref, *, bm: int, n_words: int,
-                         mark: int, only_if: int):
+def _apply_marks(idx_ref, tab, *, mark: int, only_if: int):
+    """Apply one SMEM block of ops, one masked read-modify-write of a
+    table row each, in order.  Every index is in [0, cap]: cap is field 0
+    of the trash word."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def body(i, carry):
+        elt = idx_ref[i]
+        word = elt // FIELDS_PER_WORD
+        row = word // LANES
+        sh = (2 * (elt % FIELDS_PER_WORD)).astype(jnp.uint32)
+        w = tab[pl.ds(row, 1), :]
+        hit = (lane == word % LANES) & (((w >> sh) & 3) == only_if)
+        tab[pl.ds(row, 1), :] = jnp.where(
+            hit, (w & ~(jnp.uint32(3) << sh)) | (jnp.uint32(mark) << sh), w)
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[0], body, 0)
+
+
+def _scatter_kernel(idx_ref, tab_hbm, out_hbm, *rest, mark: int,
+                    only_if: int, rotate):
+    """Grid over op blocks.  The table is copied into VMEM once, before the
+    first block, and back to HBM (the same buffer: input and output are
+    aliased) after the last.  With ``rotate = (lut, count_val, n_words,
+    rb)`` the last block also LUT-rotates and counts the resident table,
+    ``rb`` rows at a time, before the copy back."""
+    if rotate is None:
+        tab, sem = rest
+    else:
+        cnt_ref, tab, sem = rest
     blk = pl.program_id(0)
 
     @pl.when(blk == 0)
-    def _init():
-        out_ref[...] = tab_ref[...]
+    def _load():
+        copy = pltpu.make_async_copy(tab_hbm, tab, sem)
+        copy.start()
+        copy.wait()
 
-    def body(i, _):
-        elt = idx_ref[i, 0]
-        word = jnp.where(elt >= 0, elt // FIELDS_PER_WORD, n_words)
-        word = jnp.minimum(word, n_words)            # trash row for drops
-        sh = (2 * jnp.maximum(elt % FIELDS_PER_WORD, 0)).astype(jnp.uint32)
-        w = pl.load(out_ref, (pl.ds(word, 1), slice(None)))
-        field = (w >> sh) & jnp.uint32(3)
-        new_w = jnp.where(field == jnp.uint32(only_if),
-                          (w & ~(jnp.uint32(3) << sh))
-                          | (jnp.uint32(mark) << sh),
-                          w).astype(jnp.uint32)
-        pl.store(out_ref, (pl.ds(word, 1), slice(None)), new_w)
-        return 0
+    _apply_marks(idx_ref, tab, mark=mark, only_if=only_if)
 
-    jax.lax.fori_loop(0, bm, body, 0)
+    @pl.when(blk == pl.num_programs(0) - 1)
+    def _store():
+        if rotate is not None:
+            lut, count_val, n_words, rb = rotate
+
+            def rot(b, total):
+                r0 = pl.multiple_of(b * rb, rb)
+                new, c = _lut_rotate(tab[pl.ds(r0, rb), :], r0 * LANES,
+                                     n_words, lut, count_val)
+                tab[pl.ds(r0, rb), :] = new
+                return total + c
+
+            cnt_ref[0, 0] = jax.lax.fori_loop(0, tab.shape[0] // rb, rot,
+                                              jnp.int32(0))
+        copy = pltpu.make_async_copy(tab, out_hbm, sem)
+        copy.start()
+        copy.wait()
 
 
-def _scatter_prep(packed: jax.Array, idx: jax.Array, block_m: int):
-    """Shared op-index padding/clipping + table staging for the scatter
-    kernels: OOB/negative indices retarget the trash row ``n_words``."""
+def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
+                  rotate_lut=None, count_val=None):
+    """Stage the table and the op indices and run _scatter_kernel.  Out-of
+    range and negative indices retarget the trash word; the indices stay
+    a flat int32 array read one SMEM block at a time."""
     n_words = packed.shape[0]
+    rows, rb = table_layout(n_words)
+    cap = n_words * FIELDS_PER_WORD
     m = idx.shape[0]
     bm = min(block_m, max(m, 1))
     m_pad = -(-max(m, 1) // bm) * bm
-    cap = n_words * FIELDS_PER_WORD
+    idx = idx.astype(jnp.int32)
     idx = jnp.where((idx >= 0) & (idx < cap), idx, cap)
-    if m_pad != m:
-        idx = jnp.pad(idx, (0, m_pad - m), constant_values=cap)
-    idx = idx.astype(jnp.int32).reshape(m_pad, 1)
-    tab = jnp.concatenate([packed.astype(jnp.uint32),
-                           jnp.zeros((1,), jnp.uint32)]).reshape(-1, 1)
-    return idx, tab, bm, m_pad
+    idx = jnp.pad(idx, (0, m_pad - m), constant_values=cap)
+
+    rotate = (None if rotate_lut is None
+              else (rotate_lut, count_val, n_words, rb))
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    out_shape = [jax.ShapeDtypeStruct((rows, LANES), jnp.uint32)]
+    if rotate is not None:
+        out_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0),
+                                      memory_space=pltpu.SMEM))
+        out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.int32))
+    table_bytes = rows * LANES * 4
+    res = pl.pallas_call(
+        functools.partial(_scatter_kernel, mark=mark, only_if=only_if,
+                          rotate=rotate),
+        grid=(m_pad // bm,),
+        in_specs=[
+            pl.BlockSpec((bm,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.uint32),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=table_bytes + VMEM_HEADROOM,
+        ),
+        interpret=interpret,
+        name=("roomy_bitpack_scatter_mark" if rotate is None
+              else "roomy_bitpack_mark_rotate_count"),
+    )(idx, _to_table(packed, rows))
+    return res[0].reshape(-1)[:n_words], (res[1][0, 0] if rotate else None)
 
 
 def bitpack_scatter_mark(
-    packed: jax.Array,       # (W,) uint32 — must fit VMEM as (W+1, 1)
+    packed: jax.Array,       # (W,) uint32 — must fit VMEM as a (rows, 128) table
     idx: jax.Array,          # (M,) int32 element indices; OOB/negative drop
     *,
     mark: int = 2,           # value to write (static)
@@ -193,80 +283,13 @@ def bitpack_scatter_mark(
     """packed[idx] ← mark where the 2-bit field holds ``only_if`` (the
     delayed-mark apply of the implicit BFS).  Duplicate indices are safe —
     the first mark wins and later ones see ``mark`` ≠ ``only_if``."""
-    n_words = packed.shape[0]
-    idx, tab, bm, m_pad = _scatter_prep(packed, idx, block_m)
-
-    kernel = functools.partial(_scatter_mark_kernel, bm=bm, n_words=n_words,
-                               mark=mark, only_if=only_if)
-    out = pl.pallas_call(
-        kernel,
-        grid=(m_pad // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((n_words + 1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_words + 1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_words + 1, 1), jnp.uint32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-        name="roomy_bitpack_scatter_mark",
-    )(idx, tab)
-    return out[:n_words, 0]
-
-
-# ------------------------------------------- fused mark + rotate + count
-
-def _mark_rotate_count_kernel(idx_ref, tab_ref, out_ref, cnt_ref, *, bm: int,
-                              n_words: int, mark: int, only_if: int,
-                              lut: int, count_val: int, nblocks: int):
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _init():
-        out_ref[...] = tab_ref[...]
-        cnt_ref[0, 0] = jnp.int32(0)
-
-    def body(i, _):
-        elt = idx_ref[i, 0]
-        word = jnp.where(elt >= 0, elt // FIELDS_PER_WORD, n_words)
-        word = jnp.minimum(word, n_words)            # trash row for drops
-        sh = (2 * jnp.maximum(elt % FIELDS_PER_WORD, 0)).astype(jnp.uint32)
-        w = pl.load(out_ref, (pl.ds(word, 1), slice(None)))
-        field = (w >> sh) & jnp.uint32(3)
-        new_w = jnp.where(field == jnp.uint32(only_if),
-                          (w & ~(jnp.uint32(3) << sh))
-                          | (jnp.uint32(mark) << sh),
-                          w).astype(jnp.uint32)
-        pl.store(out_ref, (pl.ds(word, 1), slice(None)), new_w)
-        return 0
-
-    jax.lax.fori_loop(0, bm, body, 0)
-
-    # Last op block: the fully marked table is still resident in VMEM —
-    # rotate it through the LUT and count in place, saving the second HBM
-    # round trip a separate bitpack_lut_count pass would pay.
-    @pl.when(blk == nblocks - 1)
-    def _rotate_count():
-        w = out_ref[...]                             # (n_words + 1, 1)
-        live = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) < n_words
-        acc = jnp.zeros_like(w)
-        total = jnp.zeros((), jnp.int32)
-        for j in range(FIELDS_PER_WORD):
-            f = (w >> (2 * j)) & 3
-            nf = (jnp.uint32(lut) >> (2 * f)) & 3
-            acc = acc | (nf << (2 * j))
-            total = total + jnp.sum(
-                jnp.where(live, (nf == count_val).astype(jnp.int32), 0))
-        # The trash row soaked up dropped marks; leave it un-rotated (it is
-        # sliced away by the wrapper) and keep it out of the count.
-        out_ref[...] = jnp.where(live, acc, w)
-        cnt_ref[0, 0] = total
+    out, _ = _scatter_call(packed, idx, mark=mark, only_if=only_if,
+                           block_m=block_m, interpret=interpret)
+    return out
 
 
 def bitpack_mark_rotate_count(
-    packed: jax.Array,       # (W,) uint32 — must fit VMEM as (W+1, 1)
+    packed: jax.Array,       # (W,) uint32 — must fit VMEM as a (rows, 128) table
     idx: jax.Array,          # (M,) int32 element indices; OOB/negative drop
     lut: int,                # make_lut(...) scalar (static)
     count_val: int,          # field value to count after mapping (static)
@@ -287,36 +310,9 @@ def bitpack_mark_rotate_count(
     Equivalent to bitpack_scatter_mark followed by bitpack_lut_count, but
     the packed table crosses HBM once instead of twice per level.
     """
-    n_words = packed.shape[0]
-    idx, tab, bm, m_pad = _scatter_prep(packed, idx, block_m)
-
-    kernel = functools.partial(_mark_rotate_count_kernel, bm=bm,
-                               n_words=n_words, mark=mark, only_if=only_if,
-                               lut=lut, count_val=count_val,
-                               nblocks=m_pad // bm)
-    out, cnt = pl.pallas_call(
-        kernel,
-        grid=(m_pad // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((n_words + 1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_words + 1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_words + 1, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-        name="roomy_bitpack_mark_rotate_count",
-    )(idx, tab)
-    return out[:n_words, 0], cnt[0, 0]
+    return _scatter_call(packed, idx, mark=mark, only_if=only_if,
+                         block_m=block_m, interpret=interpret,
+                         rotate_lut=lut, count_val=count_val)
 
 
 # ------------------------------------------------- paged gather (serving)
@@ -334,10 +330,9 @@ def _gather2_kernel(tbl_ref, idx_ref, page_ref, out_ref, *, bm: int):
         ee = jnp.maximum(elt, 0)
         word = ee // FIELDS_PER_WORD
         sh = (2 * (ee % FIELDS_PER_WORD)).astype(jnp.uint32)
-        w = pl.load(page_ref, (pl.ds(word, 1), slice(None)))
+        w = page_ref[pl.ds(word, 1), :]
         f = ((w >> sh) & jnp.uint32(3)).astype(jnp.int32)
-        pl.store(out_ref, (pl.ds(i, 1), slice(None)),
-                 jnp.where(ok, f, 0))
+        out_ref[pl.ds(i, 1), :] = jnp.where(ok, f, 0)
         return 0
 
     jax.lax.fori_loop(0, bm, body, 0)
@@ -427,7 +422,7 @@ def bitpack_gather2(
         functools.partial(_gather2_kernel, bm=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks * bm, 1), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -46,8 +46,7 @@ def _scatter_kernel(idx_ref, pay_ref, tab_ref, out_ref, cur_ref, acc_ref, *,
         boundary = row_idx != cur
         # Flush the finished run to its row (or to trash if mid-run).
         tgt = jnp.where(boundary, jnp.minimum(cur, n_rows), n_rows)
-        old = pl.load(out_ref, (pl.ds(tgt, 1), slice(None)))
-        pl.store(out_ref, (pl.ds(tgt, 1), slice(None)), old + acc_ref[...])
+        out_ref[pl.ds(tgt, 1), :] = out_ref[pl.ds(tgt, 1), :] + acc_ref[...]
         pay = pay_ref[i].astype(jnp.float32)[None, :]
         acc_ref[...] = jnp.where(boundary, pay, acc_ref[...] + pay)
         cur_ref[0] = row_idx
@@ -58,8 +57,7 @@ def _scatter_kernel(idx_ref, pay_ref, tab_ref, out_ref, cur_ref, acc_ref, *,
     @pl.when(blk == nblk - 1)
     def _final_flush():
         tgt = jnp.minimum(cur_ref[0], n_rows)
-        old = pl.load(out_ref, (pl.ds(tgt, 1), slice(None)))
-        pl.store(out_ref, (pl.ds(tgt, 1), slice(None)), old + acc_ref[...])
+        out_ref[pl.ds(tgt, 1), :] = out_ref[pl.ds(tgt, 1), :] + acc_ref[...]
 
 
 def bucket_scatter_add(

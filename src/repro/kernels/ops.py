@@ -1,13 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Backend dispatch policy (DESIGN.md §7):
-  * TPU backend → pl.pallas_call (compiled Mosaic kernel)
-  * anything else (CPU CI, the 512-device dry-run) → interpret mode for
-    explicitly-requested kernel validation, otherwise the blocked jnp
-    reference, whose HLO has the same FLOP count and a matching streaming
-    memory profile (what cost_analysis reads).
-
-``impl`` arg: "auto" | "pallas" | "interpret" | "ref".
+``impl`` picks the implementation:
+  * "pallas"    the compiled Mosaic kernel (needs a TPU);
+  * "interpret" the same kernel in Pallas interpret mode (any backend;
+                how the CPU tests validate the kernels);
+  * "ref"       the pure-jnp oracle in ref.py;
+  * "auto"      "pallas" when JAX's default backend is a TPU, else "ref".
 """
 from __future__ import annotations
 
@@ -128,7 +126,8 @@ def bucket_scatter_add(table, idx, payload, *, impl="auto", block_m=256):
 
 @functools.partial(jax.jit, static_argnames=("lut", "count_val", "impl",
                                              "block_w"))
-def bitpack_lut_count(packed, lut, count_val, *, impl="auto", block_w=8):
+def bitpack_lut_count(packed, lut, count_val, *, impl="auto",
+                      block_w=_bp.ROW_BLOCK):
     """Map each 2-bit field of the packed words through the 4-entry LUT and
     count fields that map to ``count_val`` (over ALL W·16 fields — callers
     with fewer logical elements correct for their padding fields)."""
@@ -142,7 +141,7 @@ def bitpack_lut_count(packed, lut, count_val, *, impl="auto", block_w=8):
 @functools.partial(jax.jit, static_argnames=("mark", "only_if", "impl",
                                              "block_m"))
 def bitpack_scatter_mark(packed, idx, *, mark=2, only_if=0, impl="auto",
-                         block_m=256):
+                         block_m=_bp.DEFAULT_BM):
     """packed[idx]'s 2-bit field ← mark where it currently holds only_if;
     out-of-range indices dropped, duplicates safe (first mark wins)."""
     mode = _resolve(impl)
@@ -156,7 +155,8 @@ def bitpack_scatter_mark(packed, idx, *, mark=2, only_if=0, impl="auto",
 @functools.partial(jax.jit, static_argnames=("lut", "count_val", "mark",
                                              "only_if", "impl", "block_m"))
 def bitpack_mark_rotate_count(packed, idx, lut, count_val, *, mark=2,
-                              only_if=0, impl="auto", block_m=256):
+                              only_if=0, impl="auto",
+                              block_m=_bp.DEFAULT_BM):
     """Fused scatter-mark + lut-rotate + count — the implicit BFS's whole
     per-level array pass in one kernel (one HBM traversal of the packed
     words instead of two).  Semantics are exactly bitpack_scatter_mark

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams; keep both working.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -117,7 +114,7 @@ def paged_decode_attention(
         functools.partial(_kernel, ps=ps, softcap=softcap, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="roomy_paged_decode",

@@ -275,13 +275,10 @@ class TestShardedMarkSync:
     def test_bucket_exchange_mark(self, multidev):
         multidev("""
             import numpy as np, jax, jax.numpy as jnp
-            from jax.sharding import PartitionSpec as P
-            shard_map = getattr(jax, "shard_map", None)
-            if shard_map is None:
-                from jax.experimental.shard_map import shard_map
+            from jax.sharding import AxisType, PartitionSpec as P
             from repro.core import bitarray as BA
             S, nw_local, m = 4, 2, 16          # 32 elements per shard
-            mesh = jax.make_mesh((S,), ("x",))
+            mesh = jax.make_mesh((S,), ("x",), axis_types=(AxisType.Auto,))
             data = jnp.zeros((S * nw_local,), jnp.uint32)
             rng = np.random.default_rng(0)
             idx = jnp.asarray(rng.integers(0, 128, S * m).astype(np.int32))
@@ -289,9 +286,9 @@ class TestShardedMarkSync:
             def f(data, idx, valid):
                 return BA.sharded_mark_sync(data, idx, valid, "x", S,
                                             capacity=m)
-            fs = shard_map(f, mesh=mesh,
-                           in_specs=(P("x"), P("x"), P("x")),
-                           out_specs=(P("x"), P()))
+            fs = jax.shard_map(f, mesh=mesh,
+                               in_specs=(P("x"), P("x"), P("x")),
+                               out_specs=(P("x"), P()))
             out, dropped = fs(data, idx, valid)
             assert int(dropped) == 0
             got = np.asarray(BA.unpack_values(out))

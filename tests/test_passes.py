@@ -293,6 +293,25 @@ class TestTierJFusedImplicit:
         assert j_sizes == d_sizes
         assert sum(j_sizes) == total
 
+    @pytest.mark.parametrize("impl", ["ref", "interpret"])
+    @pytest.mark.parametrize("fused", [True, False])
+    def test_implicit_bfs_blocked_equals_one_block(self, impl, fused,
+                                                   monkeypatch):
+        # A block of 700 expands pancake n=7 in 8 blocks of 640 states, the
+        # last one short: each block's marks land before the next block
+        # expands, and the levels and the final array match a one-block
+        # search.
+        n = 7
+        total = math.factorial(n)
+        start = int(R.rank_np(np.arange(n)[None, :])[0])
+        nf = _pancake_neighbor_jnp(n)
+        want, wbits = C.implicit_bfs(total, [start], nf, impl="ref")
+        monkeypatch.setattr(C, "IMPLICIT_BLOCK", 700)
+        got, gbits = C.implicit_bfs(total, [start], nf, impl=impl,
+                                    fused=fused)
+        assert got == want
+        assert np.array_equal(np.asarray(gbits.data), np.asarray(wbits.data))
+
 
 # --------------------------------------- Tier J sorted-engine level budget
 
