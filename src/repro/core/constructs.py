@@ -233,6 +233,21 @@ def _bfs_level_reference(cur: RL.RoomyList, all_lst: RL.RoomyList,
 
 IMPLICIT_BLOCK = 1 << 20     # states expanded per block of an implicit level
 
+# Tier J implicit search, booked on the host whether or not tracing is on:
+# a level call expands ``states_expanded`` (padded) states, of which
+# ``frontier_states`` are CUR, the live work.
+IMPLICIT_STATS = obs.counters("implicit", {
+    "searches": 0, "level_calls": 0, "states_expanded": 0,
+    "frontier_states": 0})
+
+
+def _implicit_blocks(n_states: int, block: int):
+    """``(nblk, bs)``: the blocks an implicit level expands, and the states
+    in each, a whole number of packed words."""
+    f = BA.FIELDS_PER_WORD
+    nblk = -(-n_states // block)
+    return nblk, -(-n_states // (nblk * f)) * f
+
 
 def _implicit_level(data, *, n_states: int, neighbor_fn: Callable,
                     impl: str, fused: bool = True,
@@ -254,15 +269,16 @@ def _implicit_level(data, *, n_states: int, neighbor_fn: Callable,
     rotates and counts in a pass of its own."""
     f = BA.FIELDS_PER_WORD
     cap = data.shape[0] * f
-    nblk = -(-n_states // block)
-    bs = -(-n_states // (nblk * f)) * f          # states per block
-    src = jnp.pad(data, (0, nblk * bs // f - data.shape[0]))
+    nblk, bs = _implicit_blocks(n_states, block)
+    with jax.named_scope("block_pad"):
+        src = jnp.pad(data, (0, nblk * bs // f - data.shape[0]))
     shifts = (2 * jnp.arange(f, dtype=jnp.uint32))[:, None]
     # Block-local state ids in field-major order: row j holds field j of
     # every word, so the CUR test reads the words without an unpack.
     local = (f * jnp.arange(bs // f, dtype=jnp.int32)[None, :]
              + jnp.arange(f, dtype=jnp.int32)[:, None]).reshape(-1)
 
+    @jax.named_scope("expand")
     def targets(b):
         words = jax.lax.dynamic_slice(src, (b * (bs // f),), (bs // f,))
         cur = (((words[None, :] >> shifts) & 3) == BA.CUR).reshape(-1)
@@ -304,23 +320,35 @@ def implicit_bfs(
     DONE in ``bits``.  ``fused=False`` keeps the two-kernel reference
     composition (mark scatter, then rotate+count) for equivalence tests.
     """
-    ba = BA.make(n_states)
-    start = jnp.asarray(start_idx, jnp.int32).reshape(-1)
-    data = BA.mark_packed(ba.data, start, mark=BA.CUR, only_if=BA.UNSEEN,
-                          impl=impl)
-    level_sizes: List[int] = [int(jnp.sum(
-        (BA.unpack_values(data)[:n_states] == BA.CUR).astype(jnp.int32)))]
-    step = jax.jit(functools.partial(_implicit_level, n_states=n_states,
-                                     neighbor_fn=neighbor_fn, impl=impl,
-                                     fused=fused, block=IMPLICIT_BLOCK))
-    for _ in range(max_levels):
-        with obs.span("bfs.level", level=len(level_sizes), tier="j",
-                      engine="implicit"):
-            data, cnt = step(data)
-            c = int(cnt)
-        if c == 0:
-            break
-        level_sizes.append(c)
+    IMPLICIT_STATS["searches"] += 1
+    nblk, bs = _implicit_blocks(n_states, IMPLICIT_BLOCK)
+    with obs.span("bfs.search", n_states=n_states, tier="j",
+                  engine="implicit"):
+        with obs.span("bfs.init"):
+            ba = BA.make(n_states)
+            start = jnp.asarray(start_idx, jnp.int32).reshape(-1)
+            data = BA.mark_packed(ba.data, start, mark=BA.CUR,
+                                  only_if=BA.UNSEEN, impl=impl)
+            level_sizes: List[int] = [int(jnp.sum(
+                (BA.unpack_values(data)[:n_states] == BA.CUR)
+                .astype(jnp.int32)))]
+        step = jax.jit(functools.partial(_implicit_level, n_states=n_states,
+                                         neighbor_fn=neighbor_fn, impl=impl,
+                                         fused=fused, block=IMPLICIT_BLOCK))
+        for _ in range(max_levels):
+            frontier = level_sizes[-1]
+            with obs.span("bfs.level", level=len(level_sizes), tier="j",
+                          engine="implicit", frontier=frontier):
+                IMPLICIT_STATS["level_calls"] += 1
+                IMPLICIT_STATS["states_expanded"] += nblk * bs
+                IMPLICIT_STATS["frontier_states"] += frontier
+                with obs.span("bfs.dispatch"):
+                    data, cnt = step(data)
+                with obs.span("bfs.sync"):
+                    c = int(cnt)
+            if c == 0:
+                break
+            level_sizes.append(c)
     return level_sizes, ba._replace(data=data)
 
 

@@ -5,8 +5,8 @@ in a handful of countable quantities — passes over data, bytes
 streamed, exchange volume (paper §2–3).  This module is the one home
 for those counts plus wall-time:
 
-* a **registry** of counters / gauges / histograms that ABSORBS the
-  legacy module dicts (``extsort.STATS``, ``bitarray.STATS``,
+* a **registry** of counter namespaces that ABSORBS the legacy
+  module dicts (``extsort.STATS``, ``bitarray.STATS``,
   ``types.SORT_STATS`` stay the very same mutable dict objects —
   every existing ``STATS[k] += n`` keeps working unchanged and is
   automatically visible to snapshots/scopes/spans), and
@@ -16,7 +16,13 @@ for those counts plus wall-time:
   ``recovery.rollback``) that record the counter deltas which occurred
   inside them.  Finished spans go to a sink (disk/trace.py's JSONL
   writer) or, in shard workers, to a buffer drained over the result
-  queue at each level barrier.
+  queue at each level barrier.  An optional ``annotate`` hook also
+  opens each span as a profiler annotation (the caller passes
+  ``jax.profiler.TraceAnnotation``), which puts the span on the device
+  trace's clock.
+
+``Histogram`` is an exact power-of-two-bucket histogram for callers
+that keep their own (the serve bench's latency percentiles).
 
 Zero-cost contract (same standard as disk/faults.py): ``ACTIVE`` is
 False by default, every tracing hook starts with that single attribute
@@ -33,7 +39,7 @@ from __future__ import annotations
 import contextlib
 import math
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
 
 ACTIVE = False
 
@@ -44,8 +50,6 @@ ENV_VAR = "ROOMY_TRACE"
 # ----------------------------------------------------------------- registry
 
 _COUNTERS: Dict[str, Dict[str, int]] = {}
-_GAUGES: Dict[str, float] = {}
-_HISTS: Dict[str, "Histogram"] = {}
 
 
 def counters(namespace: str, defaults: Dict[str, int]) -> Dict[str, int]:
@@ -60,13 +64,6 @@ def counters(namespace: str, defaults: Dict[str, int]) -> Dict[str, int]:
     for k, v in defaults.items():
         d.setdefault(k, v)
     return d
-
-
-def gauge(name: str, value) -> None:
-    """Record a point-in-time value (last write wins).  ACTIVE-gated so
-    an untraced run never touches the registry."""
-    if ACTIVE:
-        _GAUGES[name] = float(value)
 
 
 class Histogram:
@@ -122,50 +119,23 @@ class Histogram:
         return float(2 ** max(self.buckets))
 
 
-def histogram(name: str) -> Histogram:
-    h = _HISTS.get(name)
-    if h is None:
-        h = _HISTS[name] = Histogram()
-    return h
-
-
-def observe(name: str, value) -> None:
-    """Book one histogram observation (latency, bytes...).  ACTIVE-gated."""
-    if ACTIVE:
-        histogram(name).observe(value)
-
-
 def snapshot() -> dict:
-    """Picklable point-in-time copy of the whole registry — what spawn
-    workers ship to the coordinator at each level barrier."""
-    return {
-        "counters": {ns: dict(d) for ns, d in _COUNTERS.items()},
-        "gauges": dict(_GAUGES),
-        "hists": {n: {"buckets": dict(h.buckets), "count": h.count,
-                      "total": h.total} for n, h in _HISTS.items()},
-    }
+    """Picklable point-in-time copy of the counters — what spawn workers
+    ship to the coordinator at each level barrier."""
+    return {"counters": {ns: dict(d) for ns, d in _COUNTERS.items()}}
 
 
 def merge(a: dict, b: dict) -> dict:
-    """Combine two snapshots: counters and histograms add, ``b``'s
-    gauges win.  Associative with the empty snapshot as identity — the
-    property the coordinator relies on when folding per-shard snapshots
-    in whatever order the result queue delivers them."""
-    out = {"counters": {}, "gauges": {}, "hists": {}}
+    """Combine two snapshots: counters add.  Associative with the empty
+    snapshot as identity — the property the coordinator relies on when
+    folding per-shard snapshots in whatever order the result queue
+    delivers them."""
+    out: Dict[str, Dict[str, Dict[str, int]]] = {"counters": {}}
     for src in (a, b):
         for ns, d in src.get("counters", {}).items():
             od = out["counters"].setdefault(ns, {})
             for k, v in d.items():
                 od[k] = od.get(k, 0) + v
-        for n, h in src.get("hists", {}).items():
-            oh = out["hists"].setdefault(
-                n, {"buckets": {}, "count": 0, "total": 0.0})
-            for bkt, c in h["buckets"].items():
-                oh["buckets"][bkt] = oh["buckets"].get(bkt, 0) + c
-            oh["count"] += h["count"]
-            oh["total"] += h["total"]
-    out["gauges"].update(a.get("gauges", {}))
-    out["gauges"].update(b.get("gauges", {}))
     return out
 
 
@@ -221,6 +191,9 @@ _SHARD: Optional[int] = None          # default shard tag for new spans
 _STACK: List["Span"] = []             # open spans (runtime is 1 thread/proc)
 _SPANS: List[dict] = []               # finished spans awaiting drain/sink
 _SINK: Optional[Callable[[dict], None]] = None
+# Opens a span's profiler annotation: annotate(sid, **scalar_attrs).
+_ANNOTATE: Optional[Callable[..., ContextManager[Any]]] = None
+_SCALARS = (bool, int, float, str)
 
 
 class _NullSpan:
@@ -243,7 +216,7 @@ _NULL = _NullSpan()
 
 class Span:
     __slots__ = ("sid", "attrs", "shard", "ts_us", "parent", "depth",
-                 "_t0", "_base")
+                 "_t0", "_base", "_ann")
 
     def __init__(self, sid: str, attrs: dict):
         self.sid = sid
@@ -260,9 +233,17 @@ class Span:
         self._base = {ns: dict(d) for ns, d in _COUNTERS.items()}
         self.ts_us = int(time.time() * 1e6)   # epoch µs: cross-process order
         self._t0 = time.perf_counter()
+        self._ann = None
+        if _ANNOTATE is not None:
+            self._ann = _ANNOTATE(self.sid, **{
+                k: v for k, v in self.attrs.items()
+                if isinstance(v, _SCALARS)})
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         dur_us = int((time.perf_counter() - self._t0) * 1e6)
         # Generator-held spans (merge streams, bucket application) can
         # close out of LIFO order — remove by identity, top down.
@@ -284,8 +265,6 @@ class Span:
             rec["attrs"] = self.attrs
         if metrics:
             rec["metrics"] = metrics
-        if ACTIVE:
-            histogram("span." + self.sid + ".us").observe(dur_us)
         _emit(rec)
         return False
 
@@ -325,24 +304,30 @@ def ingest(spans: List[dict], shard: Optional[int] = None) -> None:
 
 
 def enable(shard: Optional[int] = None,
-           sink: Optional[Callable[[dict], None]] = None) -> None:
+           sink: Optional[Callable[[dict], None]] = None,
+           annotate: Optional[Callable[..., ContextManager[Any]]] = None
+           ) -> None:
     """Turn tracing on.  ``sink`` (the coordinator's JSONL writer)
     receives finished spans immediately; without one (shard workers)
-    spans buffer for ``drain_spans()``."""
-    global ACTIVE, _SHARD, _SINK
+    spans buffer for ``drain_spans()``.  ``annotate(sid, **attrs)``, if
+    given, returns a context manager that every span enters after its
+    own clock readings and leaves before its end reading, with the
+    span's scalar attrs: pass ``jax.profiler.TraceAnnotation`` to put
+    the spans on the profiler's clock (this module never imports jax)."""
+    global ACTIVE, _SHARD, _SINK, _ANNOTATE
     _SHARD = shard
     _SINK = sink
+    _ANNOTATE = annotate
     ACTIVE = True
 
 
 def disable() -> None:
     """Turn tracing off and drop all tracing state.  Counters are NOT
     touched — they belong to their owning modules (``reset_stats()``)."""
-    global ACTIVE, _SHARD, _SINK
+    global ACTIVE, _SHARD, _SINK, _ANNOTATE
     ACTIVE = False
     _SHARD = None
     _SINK = None
+    _ANNOTATE = None
     del _STACK[:]
     del _SPANS[:]
-    _GAUGES.clear()
-    _HISTS.clear()

@@ -80,9 +80,16 @@ def table_layout(n_words: int, row_block: int = ROW_BLOCK):
     return -(-need // rb) * rb, rb
 
 
+@jax.named_scope("to_table")
 def _to_table(packed: jax.Array, rows: int) -> jax.Array:
     pad = rows * LANES - packed.shape[0]
     return jnp.pad(packed.astype(jnp.uint32), (0, pad)).reshape(rows, LANES)
+
+
+@jax.named_scope("to_table")
+def _from_table(table: jax.Array, n_words: int) -> jax.Array:
+    """The first ``n_words`` words of a ``(rows, 128)`` table, flat."""
+    return table.reshape(-1)[:n_words]
 
 
 def _lut_rotate(w, first_word, n_words: int, lut: int, count_val: int):
@@ -158,7 +165,7 @@ def bitpack_lut_count(
         interpret=interpret,
         name="roomy_bitpack_lut_count",
     )(_to_table(packed, rows))
-    return out.reshape(-1)[:w], cnt[0, 0]
+    return _from_table(out, w), cnt[0, 0]
 
 
 # ------------------------------------- scatter mark (+ rotate + count)
@@ -268,7 +275,8 @@ def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
         name=("roomy_bitpack_scatter_mark" if rotate is None
               else "roomy_bitpack_mark_rotate_count"),
     )(idx, _to_table(packed, rows))
-    return res[0].reshape(-1)[:n_words], (res[1][0, 0] if rotate else None)
+    return (_from_table(res[0], n_words),
+            res[1][0, 0] if rotate else None)
 
 
 def bitpack_scatter_mark(

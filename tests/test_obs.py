@@ -88,17 +88,12 @@ class TestZeroCost:
             s.set(extra=1)
         assert obs.drain_spans() == []
 
-    def test_gauge_and_observe_are_noops_when_off(self):
-        obs.gauge("g", 1.5)
-        obs.observe("h", 42)
-        assert obs._GAUGES == {} and obs._HISTS == {}
-
     def test_untraced_run_books_nothing(self, tmp_path):
         sizes = _implicit_levels(str(tmp_path), n=4, nshards=1)
         assert sum(sizes) == 24 and len(sizes) - 1 == 4
         assert obs.ACTIVE is False
-        assert obs.drain_spans() == []
-        assert obs._GAUGES == {} and obs._HISTS == {}
+        assert obs.drain_spans() == [] and obs._STACK == []
+        assert obs._ANNOTATE is None
         assert obs.ENV_VAR not in os.environ
         assert not [p for p in tmp_path.rglob("*.jsonl")]
 
@@ -217,18 +212,105 @@ class TestSpanMechanics:
         assert [r["sid"] for r in recs] == ["gen_held", "other"]
         assert obs._STACK == []
 
-    def test_span_duration_histogram(self):
-        obs.enable()
-        with obs.span("timed"):
-            pass
-        assert obs._HISTS["span.timed.us"].count == 1
-
     def test_histogram_pow2_buckets(self):
         h = obs.Histogram()
         for v in (0, 1, 2, 3, 4, 5, 1024):
             h.observe(v)
         assert h.buckets == {0: 2, 1: 1, 2: 2, 3: 1, 10: 1}
         assert h.count == 7 and h.total == 1039.0
+
+
+# ---------------------------------------------------------- annotate hook
+
+class _Recorder:
+    """An ``annotate`` hook that logs what it is handed and when."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, sid, **attrs):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("enter", sid, attrs,
+                                [s.sid for s in obs._STACK]))
+                return self
+
+            def __exit__(self, *exc):
+                rec.log.append(("exit", sid, exc[0]))
+                return False
+
+        return _Ann()
+
+
+class TestAnnotateHook:
+
+    def test_entered_and_left_around_the_span(self):
+        hook = _Recorder()
+        emitted = []
+        obs.enable(sink=lambda r: emitted.append(r["sid"]), annotate=hook)
+        with obs.span("outer", level=3):
+            with obs.span("inner"):
+                assert [e[:2] for e in hook.log] == [("enter", "outer"),
+                                                     ("enter", "inner")]
+        assert [e[:2] for e in hook.log[2:]] == [("exit", "inner"),
+                                                 ("exit", "outer")]
+        # Entered once the span is open (on the stack, its clock read) ...
+        assert hook.log[0][3] == ["outer"]
+        assert hook.log[1][3] == ["outer", "inner"]
+        # ... and left before its record is emitted.
+        assert emitted == ["inner", "outer"]
+
+    def test_gets_the_sid_and_scalar_attrs_only(self):
+        hook = _Recorder()
+        obs.enable(annotate=hook)
+        with obs.span("bfs.level", level=3, frontier=9, tier="j", ok=True,
+                      share=0.5, sizes=[1, 2], meta={"a": 1}, shard=4):
+            pass
+        (_, sid, attrs, _), _ = hook.log
+        assert sid == "bfs.level"
+        assert attrs == {"level": 3, "frontier": 9, "tier": "j", "ok": True,
+                         "share": 0.5}
+        (rec,) = obs.drain_spans()          # the record keeps every attr
+        assert rec["attrs"]["sizes"] == [1, 2] and rec["shard"] == 4
+
+    def test_left_with_the_exception_that_ends_the_span(self):
+        hook = _Recorder()
+        obs.enable(annotate=hook)
+        with pytest.raises(KeyError):
+            with obs.span("boom"):
+                raise KeyError("x")
+        assert hook.log[-1] == ("exit", "boom", KeyError)
+        assert obs._STACK == []
+
+    def test_never_called_while_off(self):
+        hook = _Recorder()
+        obs.enable(annotate=hook)
+        obs.disable()
+        assert obs._ANNOTATE is None
+        s = obs.span("bfs.level", level=1)
+        assert s is obs._NULL
+        with s:
+            pass
+        assert hook.log == []
+
+    def test_enable_without_the_hook_calls_nothing(self):
+        hook = _Recorder()
+        obs.enable(annotate=hook)
+        obs.enable()                          # a later enable drops it
+        with obs.span("plain"):
+            pass
+        assert hook.log == [] and len(obs.drain_spans()) == 1
+
+    def test_obs_imports_no_jax(self):
+        import subprocess
+        code = ("import sys; import repro.core.obs; "
+                "sys.exit('jax' in sys.modules)")
+        proc = subprocess.run([sys.executable, "-c", code],
+                              env=dict(os.environ), capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr
 
 
 # ------------------------------------------------------- registry + merge
@@ -239,18 +321,6 @@ _SNAP = st.fixed_dictionaries({
         st.sampled_from(["extsort", "bits", "tierj"]),
         st.dictionaries(st.sampled_from(["x", "y", "z"]), _INTS, max_size=3),
         max_size=3),
-    "gauges": st.dictionaries(st.sampled_from(["g1", "g2"]),
-                              st.integers(min_value=0, max_value=99),
-                              max_size=2),
-    "hists": st.dictionaries(
-        st.sampled_from(["h1", "h2"]),
-        st.fixed_dictionaries({
-            "buckets": st.dictionaries(st.integers(min_value=0, max_value=8),
-                                       st.integers(min_value=1, max_value=99),
-                                       max_size=3),
-            "count": st.integers(min_value=0, max_value=300),
-            "total": st.integers(min_value=0, max_value=1000)}),
-        max_size=2),
 })
 
 
@@ -269,17 +339,21 @@ class TestRegistryMerge:
         assert obs.snapshot()["counters"]["obstest2"]["n"] == d["n"]
 
     def test_merge_empty_identity(self):
-        a = {"counters": {"ns": {"k": 3}}, "gauges": {"g": 1.0},
-             "hists": {"h": {"buckets": {0: 2}, "count": 2, "total": 2.0}}}
-        empty = {"counters": {}, "gauges": {}, "hists": {}}
-        assert obs.merge(a, empty) == obs.merge(empty, a)
+        a = {"counters": {"ns": {"k": 3}}}
+        empty = {"counters": {}}
+        assert obs.merge(a, empty) == obs.merge(empty, a) == a
 
     @settings(max_examples=60, deadline=None)
     @given(_SNAP, _SNAP, _SNAP)
     def test_merge_associative(self, a, b, c):
-        # Integer-valued totals keep float addition exact, so this is
-        # true equality, not approximate: fold order can't matter.
+        # Integer counters add exactly: fold order can't matter.
         assert obs.merge(obs.merge(a, b), c) == obs.merge(a, obs.merge(b, c))
+
+    def test_snapshot_holds_counters_only(self):
+        obs.counters("obstest3", {"n": 1})
+        snap = obs.snapshot()
+        assert list(snap) == ["counters"]
+        assert snap["counters"]["obstest3"] == {"n": 1}
 
     def test_counter_deltas_flat_nonzero(self):
         before = {"counters": {"ns": {"a": 1, "b": 2}}}
