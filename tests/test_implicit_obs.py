@@ -4,7 +4,9 @@
 ``bfs.dispatch``, ``bfs.sync`` while tracing is on, and books the
 ``implicit`` counter namespace whether or not it is: one search, one level
 call per level, the padded states each call expands and the frontier
-states entering it.
+states entering it, and per search one step hit or miss: the jitted level
+step is kept across searches per (n_states, rule object, impl, fused,
+block).
 """
 import math
 import os
@@ -23,22 +25,41 @@ sys.path.append(os.path.join(os.path.dirname(
 from pancake_bits import neighbor_jnp                 # noqa: E402
 
 N = 6
+PANCAKE5 = [1, 4, 12, 35, 48, 20]
 PANCAKE6 = [1, 5, 20, 79, 199, 281, 133, 2]
 
 
 @pytest.fixture(autouse=True)
 def _clean_obs():
+    # Every test starts with no level step kept, whatever ran before it.
+    C._implicit_step.cache_clear()
     yield
     if trace._SESSION is not None:
         trace.stop()
     obs.disable()
 
 
-def _search(n=N, **kw):
+def _counted(n=N):
+    """The pancake rule, and the list its Python body appends to on each
+    call: it runs only while the level step is traced."""
+    calls = []
+    nf = neighbor_jnp(n)
+
+    def rule(i):
+        calls.append(i)
+        return nf(i)
+    return rule, calls
+
+
+def _run(rule, n_states=math.factorial(N), n=N, impl="ref", **kw):
     start = int(R.rank_np(np.arange(n)[None, :])[0])
-    sizes, _ = C.implicit_bfs(math.factorial(n), [start], neighbor_jnp(n),
-                              impl="interpret", **kw)
-    return sizes
+    sizes, bits = C.implicit_bfs(n_states, [start], rule, impl=impl, **kw)
+    return sizes, np.asarray(bits.data)
+
+
+def _search(n=N, **kw):
+    return _run(neighbor_jnp(n), math.factorial(n), n, impl="interpret",
+                **kw)[0]
 
 
 def _padded(n_states):
@@ -95,10 +116,15 @@ def test_traced_search_records_the_span_tree():
     assert levels[2]["metrics"] == {
         "implicit.level_calls": 1, "implicit.states_expanded": 720,
         "implicit.frontier_states": sizes[2]}
+    # The step is looked up once, inside the search and outside its levels.
+    assert search["metrics"]["implicit.step_misses"] == 1
+    assert not any(k.startswith("implicit.step_")
+                   for lv in levels for k in lv["metrics"])
     got = sc.delta()["implicit"]
     assert got == {"searches": 1, "level_calls": len(sizes),
                    "states_expanded": len(sizes) * _padded(720),
-                   "frontier_states": math.factorial(N)}
+                   "frontier_states": math.factorial(N),
+                   "step_hits": 0, "step_misses": 1}
 
 
 def test_untraced_search_books_counters_and_no_spans():
@@ -110,7 +136,8 @@ def test_untraced_search_books_counters_and_no_spans():
     assert sc.delta()["implicit"] == {
         "searches": 1, "level_calls": len(sizes),
         "states_expanded": len(sizes) * 720,
-        "frontier_states": math.factorial(N)}
+        "frontier_states": math.factorial(N),
+        "step_hits": 0, "step_misses": 1}
 
 
 def test_level_cap_books_only_the_calls_made():
@@ -158,3 +185,95 @@ def test_jsonl_level_rows_ignore_the_child_spans(tmp_path):
     assert [r["wall_us"] for r in rows] == level_us
     assert set(summary) == {"type", "counters"}
     assert summary["counters"]["implicit"]["level_calls"] >= len(sizes)
+
+
+# --------------------------------------------- the level step kept across searches
+
+def test_a_repeated_search_keeps_its_step():
+    rule, calls = _counted()
+    with obs.scope() as sc:
+        sizes1, bits1 = _run(rule)
+        traced = len(calls)
+        sizes2, bits2 = _run(rule)
+    assert traced > 0 and len(calls) == traced      # not traced again
+    assert sizes1 == sizes2 == PANCAKE6
+    assert np.array_equal(bits1, bits2)
+    got = sc.delta()["implicit"]
+    assert (got["searches"], got["step_misses"], got["step_hits"]) == (2, 1, 1)
+
+
+@pytest.mark.parametrize("change", ["n_states", "rule", "impl", "fused",
+                                    "block"])
+def test_a_changed_input_gets_a_fresh_step(change, monkeypatch):
+    rule, calls = _counted(5)
+    first = _run(rule, n_states=120, n=5)
+    traced = len(calls)
+    kw = {}
+    if change == "n_states":
+        # The n=5 rule over a larger space: no state past 5! is reached,
+        # so the levels are pancake-5's.
+        kw["n_states"] = 200
+    elif change == "rule":
+        rule, calls = _counted(5)
+        traced = 0
+    elif change == "impl":
+        kw["impl"] = "interpret"
+    elif change == "fused":
+        kw["fused"] = False
+    else:
+        monkeypatch.setattr(C, "IMPLICIT_BLOCK", 64)     # 2 blocks of 64
+    kw = {"n_states": 120, "n": 5, **kw}
+    with obs.scope() as sc:
+        sizes, bits = _run(rule, **kw)
+        retraced = len(calls)
+        assert _run(rule, **kw)[0] == sizes              # then kept
+    assert retraced > traced and len(calls) == retraced
+    assert sizes == first[0] == PANCAKE5
+    if change != "n_states":
+        assert np.array_equal(bits, first[1])
+    got = sc.delta()["implicit"]
+    assert (got["searches"], got["step_misses"], got["step_hits"]) == (2, 1, 1)
+
+
+def test_each_search_books_one_hit_or_one_miss():
+    rule, _ = _counted(4)
+    other, _ = _counted(4)
+    with obs.scope() as sc:
+        for r in (rule, rule, other, rule, other):
+            _run(r, n_states=24, n=4)
+    got = sc.delta()["implicit"]
+    assert got["searches"] == 5
+    assert (got["step_misses"], got["step_hits"]) == (2, 3)
+
+
+def test_the_kept_steps_are_bounded():
+    kept = C._implicit_step.cache_info().maxsize
+    rules = [_counted(4) for _ in range(kept + 1)]
+    for rule, _ in rules:
+        _run(rule, n_states=24, n=4)
+    assert C._implicit_step.cache_info().currsize == kept
+    (oldest, calls), (newest, _) = rules[0], rules[-1]
+    with obs.scope() as sc:
+        _run(newest, n_states=24, n=4)
+        traced = len(calls)
+        _run(oldest, n_states=24, n=4)                   # was dropped
+    assert len(calls) > traced
+    got = sc.delta()["implicit"]
+    assert (got["step_misses"], got["step_hits"]) == (1, 1)
+
+
+def test_a_warmed_up_window_only_hits():
+    # A one-level warm-up with the rule object, then whole searches from
+    # other starts with the same object: only the warm-up builds a step.
+    rule, calls = _counted(5)
+    starts = [0, 7, 42, 119]
+    with obs.scope() as sc:
+        C.implicit_bfs(120, [3], rule, max_levels=1, impl="ref")
+        traced = len(calls)
+        for start in starts:
+            sizes, _ = C.implicit_bfs(120, [start], rule, impl="ref")
+            assert sizes == PANCAKE5          # a Cayley graph: any start
+    assert traced > 0 and len(calls) == traced
+    got = sc.delta()["implicit"]
+    assert got["searches"] == 1 + len(starts)
+    assert (got["step_misses"], got["step_hits"]) == (1, len(starts))
