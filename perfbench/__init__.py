@@ -1,1 +1,1 @@
-"""Chip benchmark of the Tier J implicit BFS (see harness.py and run.py)."""
+"""Chip benchmark of Roomy's searches (see harness.py and run.py)."""

@@ -4,12 +4,14 @@ program's place with its guarantee broken, which the check has to fail.
 
     python3 perfbench/control.py --workload pancake-10.search --seeds 1 2 3
 
-For each seed it searches the cell's graph at the cell's size from a start
-drawn from the seed, with lost updates (``reference.level_counts(...,
-lost_updates=True)``: marks to states that share a packed word clobber
-each other), and compares the counts with the reference's as a run does.
-It prints, per seed, each number compared beside its limit and whether the
-run would have been correct.  The benchmark's own runs do not run it.
+For each seed it asks the configuration's search (``harness.load_search``)
+for its control at the cell's size, from a start drawn from the seed, and
+compares the control's level counts with the reference's as a run does.
+For a Cayley graph the control is the reference with lost updates
+(``reference.level_counts(..., lost_updates=True)``: marks to states that
+share a packed word clobber each other).  It prints, per seed, each number
+compared beside its limit and whether the run would have been correct.
+The benchmark's own runs do not run it.
 """
 from __future__ import annotations
 
@@ -26,21 +28,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def control_readings(spec, cell_name: str, seeds, bench_dir=None):
     """[(seed, numbers, correct)] of the control in the program's place."""
-    from perfbench import harness, reference
+    from perfbench import harness
     bench_dir = bench_dir or harness.BENCH_DIR
     cell = harness.find_cell(spec, cell_name)
     config = harness.load_config(cell["config"], bench_dir)
     traffic = harness.load_traffic(cell_name, bench_dir)
-    n, gens = config["n"], config["generators"]
-    ref = reference.level_counts(n, gens)
+    graph = harness.load_search(config, bench_dir)
+    if graph.control is None:
+        raise ValueError(f"configuration {cell['config']!r} has no control")
     out = []
     for seed in seeds:
-        start = np.random.default_rng(seed).permutation(n)
         t0 = time.perf_counter()
-        sizes = reference.level_counts(n, gens, lost_updates=True,
-                                       start=start)
-        search = harness.Search(0, sizes, time.perf_counter() - t0)
-        verdict = harness.check([search], ref, traffic)
+        got, want = graph.control(np.random.default_rng(seed))
+        search = harness.Search(0, got, 0, time.perf_counter() - t0)
+        verdict = harness.check([search], [want], traffic)
         out.append((seed, verdict["numbers"], verdict["correct"]))
     return out
 
@@ -56,8 +57,7 @@ def main(argv=None) -> int:
     for seed, numbers, correct in control_readings(spec, args.workload,
                                                    args.seeds):
         print(json.dumps({"workload": args.workload, "seed": seed,
-                          "control": "lost_updates", "correct": correct,
-                          "checks": numbers}))
+                          "correct": correct, "checks": numbers}))
     return 0
 
 

@@ -1,21 +1,40 @@
-"""The chip benchmark of the Tier J implicit BFS, driven by data.
+"""The chip benchmark, driven by data.
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix.  The
 harness finds everything by those names:
 
-  configs/<config>.json      the graph: n, its generators (the plain
-                             reference's definition) and the program's
-                             neighbour rule, as a file and a function
+  configs/<config>.json      the configuration: its sizes and, under
+                             ``"search"``, the file and function that turn
+                             it into the calls below (``load_search``)
   workloads/<cell>.json      the traffic: its description and the limit
                              of each number that decides ``correct``
   metrics/<metric>.py        one reader per metric, ``read(ctx)``
   peaks.json                 the chip's peaks, keyed by ``device_kind``
 
+The object a configuration's search function returns, given the
+configuration and the root its paths are relative to, provides:
+
+  n_states, fanout           the size of the state space and the
+                             neighbours of a state, for the roofline
+  scopes                     the program's named scopes that its device
+                             ops carry, for ``Context.program``
+  draw_start(rng)            one search's start, drawn from the run's rng
+  run(start, max_levels=None)
+                             one search through the program, whole or cut
+                             to ``max_levels`` levels: (level counts, the
+                             device arrays to wait for)
+  states(sizes)              the states one whole search covers
+  reference(start)           the plain reference's level counts from start
+  control(rng)               the control's level counts from a start drawn
+                             from rng, and the reference's; ``control`` is
+                             None where the configuration has none
+
 One run: set-up (imports, the compile cache, one search cut to one level
-so that the level step compiles or loads), then whole searches through
-``repro.core.constructs.implicit_bfs`` back to back for about
-``--seconds``, then the plain reference (``reference.py``) and the check of
-every timed search's level counts against it.  ``run.py`` is the command.
+so that its programs compile or load), then whole searches back to back
+for about ``--seconds``, then the plain reference and the check of every
+timed search's level counts against the reference's from its own start.
+A traced run also turns the program's obs spans on for the window, each a
+profiler annotation.  ``run.py`` is the command.
 """
 from __future__ import annotations
 
@@ -23,18 +42,17 @@ import contextlib
 import gc
 import importlib.util
 import json
-import math
 import os
 import shutil
 import statistics
 import sys
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference
+from . import spanfold
 from . import tracefold
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -46,6 +64,7 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
 CACHE_EVENTS = ("/jax/compilation_cache/cache_hits",
                 "/jax/compilation_cache/cache_misses")
+TOP = 10          # entries of each list of the breakdown
 
 
 class NoChip(RuntimeError):
@@ -100,6 +119,17 @@ def load_file(path: str, attr: str):
     return getattr(mod, attr)
 
 
+def load_search(config: Dict, bench_dir: str = BENCH_DIR):
+    """The object of the module's docstring for a configuration: its
+    ``"search"`` function, called with the configuration and the root that
+    the configuration's paths are relative to (the bench directory's
+    parent)."""
+    root = os.path.dirname(os.path.abspath(bench_dir))
+    make = load_file(os.path.join(root, config["search"]["file"]),
+                     config["search"]["function"])
+    return make(config, root)
+
+
 def load_metric(name: str, bench_dir: str = BENCH_DIR) -> Callable:
     return load_file(os.path.join(bench_dir, "metrics", f"{name}.py"), "read")
 
@@ -122,9 +152,11 @@ def level_gap(got: List[int], want: List[int]) -> int:
     return max(abs(x - y) for x, y in zip(a, b))
 
 
-def check(searches: List["Search"], ref: List[int], traffic: Dict) -> Dict:
-    """The numbers compared, each with its limit; and the failed searches."""
-    gaps = [level_gap(s.sizes, ref) for s in searches]
+def check(searches: List["Search"], refs: List[List[int]],
+          traffic: Dict) -> Dict:
+    """The numbers compared, each with its limit; and the failed searches.
+    ``refs`` holds the reference's level counts for each search's start."""
+    gaps = [level_gap(s.sizes, ref) for s, ref in zip(searches, refs)]
     limit = traffic["limits"]["worst_level_gap"]
     return {"numbers": {"worst_level_gap": {"value": max(gaps, default=None),
                                             "limit": limit}},
@@ -136,8 +168,9 @@ def check(searches: List["Search"], ref: List[int], traffic: Dict) -> Dict:
 
 @dataclass
 class Search:
-    start: int
+    start: Any
     sizes: List[int]
+    states: int                           # the states the search covers
     seconds: float
     peak_bytes: Optional[int] = None      # the device's peak after it
 
@@ -147,21 +180,16 @@ class Context:
     """What the metric readers read."""
     config: Dict
     traffic: Dict
+    graph: Any                            # load_search's object
     searches: List[Search]
     window_s: float
     setup_s: float
     memory_peak_bytes: Optional[int]
     peaks: Dict
     compile_spans: List = field(default_factory=list)   # (event, t0, t1)
-    trace: Optional[Dict] = None                         # tracefold.fold
-
-    @property
-    def n_states(self) -> int:
-        return math.factorial(self.config["n"])
-
-    @property
-    def fanout(self) -> int:
-        return len(self.config["generators"])
+    counters: Dict = field(default_factory=dict)  # the window's obs deltas
+    trace: Optional[Dict] = None                  # tracefold.fold
+    program: Optional[Dict] = None                # spanfold.fold
 
 
 class CompileRecorder:
@@ -210,28 +238,44 @@ def peak_bytes(dev) -> Optional[int]:
     return (dev.memory_stats() or {}).get("peak_bytes_in_use")
 
 
-def _searches(total: int, rule, rng, seconds: float, dev) -> List[Search]:
+def _searches(graph, rng, seconds: float, dev) -> List[Search]:
     """Whole single-source searches back to back, as many as bring the end
     of the window nearest to ``seconds``: another search starts while the
     window, with half a search of the mean length so far, is shorter than
     ``seconds``.  At least one runs."""
     import jax
-    from repro.core import constructs as C
     done: List[Search] = []
     t_window = time.perf_counter()
     while True:
-        start = int(rng.integers(0, total))
+        start = graph.draw_start(rng)
         with jax.profiler.TraceAnnotation(tracefold.SEARCH_ANNOTATION):
             t0 = time.perf_counter()
-            sizes, bits = C.implicit_bfs(total, [start], rule, impl="auto")
-            jax.block_until_ready(bits.data)
-            done.append(Search(start, sizes, time.perf_counter() - t0,
-                               peak_bytes(dev)))
-        del bits
+            sizes, arrays = graph.run(start)
+            jax.block_until_ready(arrays)
+            done.append(Search(start, sizes, graph.states(sizes),
+                               time.perf_counter() - t0, peak_bytes(dev)))
+        del arrays
         elapsed = time.perf_counter() - t_window
         mean = statistics.fmean(s.seconds for s in done)
         if elapsed + mean / 2 >= seconds:
             return done
+
+
+def _ranked(seconds: Dict[str, float]) -> List:
+    return [[k, v] for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])]
+
+
+def _print_program(program: Dict, log) -> None:
+    """One line per search: seconds under each span name and the
+    device-idle seconds under each; then the levels of the first search."""
+    for i, s in enumerate(program["per_search"]):
+        spans = ", ".join(f"{k} {v:.4f}"
+                          for k, v in sorted(s["span_s"].items()))
+        idle = ", ".join(f"{k} {v:.4f}" for k, v in _ranked(s["idle_s"]))
+        print(f"search {i} ({s['seconds']:.4f} s): {spans}; "
+              f"device idle under {idle}", file=log)
+    print("levels of the first search (level, frontier, device s, host s): "
+          f"{program['levels']}", file=log)
 
 
 def run_cell(spec: Dict, cell_name: str, seed: int, seconds: float,
@@ -240,20 +284,17 @@ def run_cell(spec: Dict, cell_name: str, seed: int, seconds: float,
              log=sys.stderr) -> Dict:
     """One run of one cell; returns the result line as a dict."""
     import jax
+    from repro.core import obs
     cell = find_cell(spec, cell_name)
     dev = chip_check(cell["chips"])
     peaks = load_peaks(dev.device_kind, bench_dir)
     config = load_config(cell["config"], bench_dir)
     traffic = load_traffic(cell_name, bench_dir)
-    rule = load_file(os.path.join(ROOT, config["rule"]["file"]),
-                     config["rule"]["function"])(config["n"])
-    total = math.factorial(config["n"])
+    graph = load_search(config, bench_dir)
 
-    # Set-up: the level step of this cell's shapes compiles or loads.
-    from repro.core import constructs as C
+    # Set-up: the programs of this cell's shapes compile or load.
     rng = np.random.default_rng(seed)
-    C.implicit_bfs(total, [int(rng.integers(0, total))], rule, max_levels=1,
-                   impl="auto")
+    jax.block_until_ready(graph.run(graph.draw_start(rng), max_levels=1)[1])
     setup_s = time.perf_counter() - t_start
     setup_peak = peak_bytes(dev)
 
@@ -264,25 +305,33 @@ def run_cell(spec: Dict, cell_name: str, seed: int, seconds: float,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        with recorder.recording():
+        if traced:
+            obs.enable(annotate=jax.profiler.TraceAnnotation)
+        with recorder.recording(), obs.scope() as counted:
             t0 = time.perf_counter()
-            searches = _searches(total, rule, rng, seconds, dev)
+            searches = _searches(graph, rng, seconds, dev)
             window_s = time.perf_counter() - t0
     finally:
         if traced:
+            # The names of the spans the window opened, which the trace
+            # is read for; obs holds them until it is turned off.
+            span_names = {rec["sid"] for rec in obs.drain_spans()}
+            obs.disable()
             jax.profiler.stop_trace()
     peak = peak_bytes(dev)
     gc.collect()
 
-    ref = reference.level_counts(config["n"], config["generators"])
-    verdict = check(searches, ref, traffic)
-    folded = None
+    refs = [graph.reference(s.start) for s in searches]
+    verdict = check(searches, refs, traffic)
+    folded = program = None
     if traced:
-        folded = tracefold.fold(
-            tracefold.load_xplane(tracefold.find_xplane(trace_dir)),
-            KERNEL_TAG)
-    ctx = Context(config, traffic, searches, window_s, setup_s, peak, peaks,
-                  recorder.spans, folded)
+        path = tracefold.find_xplane(trace_dir)
+        raw = tracefold.load_xplane(path)
+        raw.update(spanfold.load_program(path, span_names, graph.scopes))
+        folded = tracefold.fold(raw, KERNEL_TAG)
+        program = spanfold.fold(raw)
+    ctx = Context(config, traffic, graph, searches, window_s, setup_s, peak,
+                  peaks, recorder.spans, counted.delta(), folded, program)
     metrics = {}
     for m in cell_metrics(spec, cell_name, traced):
         value = load_metric(m["name"], bench_dir)(ctx)
@@ -297,14 +346,21 @@ def run_cell(spec: Dict, cell_name: str, seed: int, seconds: float,
     if traced:
         device["busy_s"] = folded["busy_s"]
         device["window_s"] = folded["window_s"]
-        result["breakdown"] = {"device_ops": folded["device_ops"],
-                               "idle_gaps": folded["idle_gaps"]}
-    print(f"searches (start, seconds): "
-          f"{[(s.start, s.seconds) for s in searches]}", file=log)
+        result["breakdown"] = {
+            "device_ops": folded["device_ops"],
+            "idle_gaps": folded["idle_gaps"],
+            "idle_by_span": _ranked(program["idle_by_span"])[:TOP],
+            "device_by_scope": _ranked(program["device_by_scope"])[:TOP]}
+    print(f"searches (start, states, seconds): "
+          f"{[(s.start, s.states, s.seconds) for s in searches]}", file=log)
     print(f"device peak bytes after set-up {setup_peak}, after each search "
           f"{[s.peak_bytes for s in searches]}", file=log)
+    moved = {ns: d for ns, d in ctx.counters.items() if any(d.values())}
+    print(f"program counters in the window: {moved}", file=log)
+    if traced:
+        _print_program(program, log)
     print(f"level counts of the first search: {searches[0].sizes}", file=log)
-    print(f"reference level counts: {ref}", file=log)
+    print(f"reference level counts from its start: {refs[0]}", file=log)
     print(f"compile cache in the window: {recorder.counts}", file=log)
     for name, num in verdict["numbers"].items():
         print(f"check {name}: {num['value']} (limit {num['limit']})", file=log)

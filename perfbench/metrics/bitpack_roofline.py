@@ -23,7 +23,7 @@ def search_bytes(n_states, fanout, sizes):
 def read(ctx):
     if ctx.trace is None or not ctx.trace["n_kernel_events"]:
         return None
-    need = sum(search_bytes(ctx.n_states, ctx.fanout, s.sizes)
+    need = sum(search_bytes(ctx.graph.n_states, ctx.graph.fanout, s.sizes)
                for s in ctx.searches)
     least_s = need / ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * least_s / ctx.trace["kernel_s"]

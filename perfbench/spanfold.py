@@ -6,10 +6,11 @@ jax.profiler.TraceAnnotation)`` each is also a profiler annotation, on the
 device trace's clock.  ``load_program`` reads, from an ``.xplane.pb``, the
 two parts of a trace that ``tracefold.load_xplane`` leaves out:
 
-  program  the program's spans (``PROGRAM_SPANS``) as
-           ``[name, start_ns, dur_ns, stats]``;
+  program  the program's spans of the names given (the harness gives
+           those the window opened) as ``[name, start_ns, dur_ns, stats]``;
   scoped   the device ops of the first TPU plane's ``XLA Ops`` line, each
-           named by its program scope (``PROGRAM_SCOPES``, a
+           named by its program scope among those given (the harness gives
+           the configuration's search's, such as ``PROGRAM_SCOPES``, each a
            ``jax.named_scope``) or ``""``: the ``SCOPE_STAT`` of the op's
            event metadata, which ``ProfileData`` does not expose, so these
            are read from the protobuf itself.
@@ -31,24 +32,28 @@ two parts of a trace that ``tracefold.load_xplane`` leaves out:
 A trace without program spans (a program that has no annotation hook)
 gives empty tables and every idle stretch under ``outside_program``.
 ``metrics`` turns a fold and the window's ``implicit`` counters into the
-search driver's and the level's numbers.
+search driver's and the level's numbers; ``reading`` takes one of them
+from a run's ``harness.Context``, for the per-layer metrics' readers.
 """
 from __future__ import annotations
 
 import bisect
+import statistics
 from collections import defaultdict
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Sequence, Tuple
 
 from . import tracefold
 
-# The Tier J search driver's spans (core/constructs.py implicit_bfs).
-PROGRAM_SPANS = ("bfs.search", "bfs.init", "bfs.level", "bfs.dispatch",
-                 "bfs.sync")
-# The named scopes of the level's ops (core/constructs.py _implicit_level,
-# kernels/bitpack.py); an op takes the innermost one of its name stack.
+# The named scopes of the Tier J implicit level's ops (core/constructs.py
+# _implicit_level, kernels/bitpack.py); an op takes the innermost one of
+# its name stack.
 PROGRAM_SCOPES = ("expand", "block_pad", "to_table")
 # The stat of an op's event metadata that holds its name stack on a TPU.
 SCOPE_STAT = "tf_op"
+# The spans of a search in which the device waits on the host's round trip
+# of a level: the level calls' spans and the search's own stretches between
+# them.  A misaligned host clock moves an idle stretch among these, not out.
+ROUND_TRIP_SPANS = ("bfs.level", "bfs.dispatch", "bfs.sync", "bfs.search")
 # The scopes of the level's copies: the block pad and the kernels' table.
 COPY_SCOPES = ("block_pad", "to_table")
 
@@ -58,11 +63,11 @@ UNSCOPED = "unscoped"
 Interval = Tuple[int, int]
 
 
-def program_scope(name_stack: str) -> str:
-    """The innermost of ``PROGRAM_SCOPES`` in an op's name stack
+def program_scope(name_stack: str, scopes: Sequence[str]) -> str:
+    """The innermost of ``scopes`` in an op's name stack
     (``jit(f)/while/body/expand/vmap()/rev`` gives ``expand``), or ``""``."""
     for part in reversed(name_stack.split("/")):
-        if part in PROGRAM_SCOPES:
+        if part in scopes:
             return part
     return ""
 
@@ -117,9 +122,11 @@ def _xplane_schema():
         pool.FindMessageTypeByName("perfbench_xplane.XSpace"))
 
 
-def scoped_ops(path: str, plane_name: str) -> List[List]:
+def scoped_ops(path: str, plane_name: str,
+               scopes: Sequence[str]) -> List[List]:
     """``[scope, start_ns, dur_ns]`` for each op of one plane's ``XLA Ops``
-    line: its program scope, from the ``SCOPE_STAT`` of its metadata."""
+    line: its program scope among ``scopes``, from the ``SCOPE_STAT`` of
+    its metadata."""
     space = _xplane_schema()()
     with open(path, "rb") as fh:
         space.ParseFromString(fh.read())
@@ -134,7 +141,7 @@ def scoped_ops(path: str, plane_name: str) -> List[List]:
                 if stat_name.get(st.metadata_id) == SCOPE_STAT:
                     scope_of[entry.key] = program_scope(
                         st.str_value if st.HasField("str_value")
-                        else stat_name.get(st.ref_value, ""))
+                        else stat_name.get(st.ref_value, ""), scopes)
         for line in plane.lines:
             if line.name == tracefold.DEVICE_LINE:
                 out.extend([scope_of.get(e.metadata_id, ""),
@@ -143,8 +150,11 @@ def scoped_ops(path: str, plane_name: str) -> List[List]:
     return out
 
 
-def load_program(path: str) -> Dict[str, List[List]]:
-    """The program's spans and the scoped device ops of one trace."""
+def load_program(path: str, spans: Iterable[str],
+                 scopes: Sequence[str]) -> Dict[str, List[List]]:
+    """The program's spans named in ``spans`` and the device ops of one
+    trace, each with its scope among ``scopes``."""
+    spans = set(spans)
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     program: List[List] = []
@@ -153,10 +163,11 @@ def load_program(path: str) -> Dict[str, List[List]]:
             for line in plane.lines:
                 program.extend([e.name, int(e.start_ns), int(e.duration_ns),
                                 dict(e.stats)] for e in line.events
-                               if e.name in PROGRAM_SPANS)
+                               if e.name in spans)
     device_planes = sorted(p.name for p in data.planes
                            if p.name.startswith("/device:TPU:"))
-    scoped = scoped_ops(path, device_planes[0]) if device_planes else []
+    scoped = (scoped_ops(path, device_planes[0], scopes) if device_planes
+              else [])
     return {"program": program, "scoped": scoped}
 
 
@@ -311,8 +322,9 @@ def metrics(program: Dict, counters: Dict[str, int],
     are times; each left out where the trace or the counters lack what it
     reads.
 
-      driver.dispatch_idle_s_per_search  device-idle seconds under
-                                         ``bfs.dispatch``
+      driver.round_trip_idle_s_per_search
+                                         the median search's device-idle
+                                         seconds under ``ROUND_TRIP_SPANS``
       driver.init_s_per_search           wall seconds of ``bfs.init``
       level.frontier_share               100 x ``implicit.frontier_states``
                                          over ``implicit.states_expanded``
@@ -321,9 +333,10 @@ def metrics(program: Dict, counters: Dict[str, int],
                                          ``block_pad`` and ``to_table``
     """
     out: Dict[str, float] = {}
-    if "bfs.dispatch" in program["span_s"]:
-        out["driver.dispatch_idle_s_per_search"] = program[
-            "idle_by_span"].get("bfs.dispatch", 0.0) / n_searches
+    if "bfs.level" in program["span_s"] and program["per_search"]:
+        out["driver.round_trip_idle_s_per_search"] = statistics.median(
+            sum(s["idle_s"].get(k, 0.0) for k in ROUND_TRIP_SPANS)
+            for s in program["per_search"])
     if "bfs.init" in program["span_s"]:
         out["driver.init_s_per_search"] = (program["span_s"]["bfs.init"]
                                            / n_searches)
@@ -337,3 +350,12 @@ def metrics(program: Dict, counters: Dict[str, int],
     if copies:
         out["level.copy_s_per_search"] = sum(copies) / n_searches
     return out
+
+
+def reading(ctx, name: str):
+    """``metrics``' number ``name`` for a run's ``harness.Context``: None
+    for an untraced run, and where the run lacks what the number reads."""
+    if ctx.program is None or not ctx.searches:
+        return None
+    return metrics(ctx.program, ctx.counters.get("implicit", {}),
+                   len(ctx.searches)).get(name)

@@ -84,6 +84,11 @@ def test_discovery_finds_every_named_file():
         assert len(cfg["generators"]) == cfg["n"] - 1
         assert callable(harness.load_file(os.path.join(
             ROOT, cfg["rule"]["file"]), cfg["rule"]["function"]))
+        assert cfg["search"] == {"file": "perfbench/configs/cayley.search.py",
+                                 "function": "Cayley"}
+        graph = harness.load_search(cfg)
+        assert graph.n_states == math.factorial(cfg["n"])
+        assert graph.fanout == cfg["n"] - 1
     for cell in spec["workloads"]:
         assert harness.load_traffic(cell["name"])["traffic"] == cell["traffic"]
         for traced in (False, True):
@@ -193,7 +198,9 @@ def test_last_line_schema(small_bench, traced):
         assert set(m) == {"value", "unit"}
     if traced:
         assert {"busy_s", "window_s"} <= set(dev)
-        assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+        assert set(result["breakdown"]) == {"device_ops", "idle_gaps",
+                                            "idle_by_span", "device_by_scope"}
+        assert all(len(v) <= 10 for v in result["breakdown"].values())
         assert "driver.compile_s_per_search" in result["metrics"]
     else:
         assert {"states_per_s", "setup_s"} <= set(result["metrics"])

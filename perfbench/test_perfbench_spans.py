@@ -1,5 +1,5 @@
 """Tests of the reduction of the program's spans and op scopes
-(``spanfold.py``) and of the span report (``spanreport.py``), on the CPU.
+(``spanfold.py``) and of what a traced run reports from them, on the CPU.
 
 The traced runs use ``small_bench`` of the harness's tests: the
 benchmark's files with each graph cut to n=5, and JAX's CPU device in
@@ -7,14 +7,16 @@ place of the chip.
 """
 from __future__ import annotations
 
+import ast
 import io
 import json
 import os
+import subprocess
+import sys
 
-import jax
 import pytest
 
-from perfbench import harness, spanfold, spanreport, tracefold
+from perfbench import harness, spanfold, tracefold
 from perfbench.test_perfbench_harness import (FIXTURE, cpu_device,  # noqa: F401
                                               small_bench)
 from repro.core import constructs as C
@@ -22,7 +24,7 @@ from repro.core import obs
 
 # fold's output on the recorded trace, as the benchmark first computed it.
 FOLDED = os.path.join(harness.BENCH_DIR, "fixtures", "fold_pancake8.json")
-PROGRAM_METRICS = ("driver.dispatch_idle_s_per_search",
+PROGRAM_METRICS = ("driver.round_trip_idle_s_per_search",
                    "driver.init_s_per_search", "level.frontier_share",
                    "level.expand_s_per_search", "level.copy_s_per_search")
 
@@ -50,7 +52,7 @@ def test_fold_output_is_pinned_and_ignores_the_program_lists():
     ("", ""),
 ])
 def test_program_scope_is_the_innermost_named_scope(stack, scope):
-    assert spanfold.program_scope(stack) == scope
+    assert spanfold.program_scope(stack, spanfold.PROGRAM_SCOPES) == scope
 
 
 def test_scoped_ops_read_each_op_scope_from_its_metadata(tmp_path):
@@ -75,7 +77,8 @@ def test_scoped_ops_read_each_op_scope_from_its_metadata(tmp_path):
         name=tracefold.DEVICE_LINE).events.add(metadata_id=2)
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(space.SerializeToString())
-    assert spanfold.scoped_ops(str(path), "/device:TPU:0") == [
+    assert spanfold.scoped_ops(str(path), "/device:TPU:0",
+                               spanfold.PROGRAM_SCOPES) == [
         ["to_table", 1005, 2], ["expand", 1009, 1], ["", 1012, 0],
         ["", 1020, 3]]
 
@@ -144,11 +147,36 @@ def test_metrics_read_the_program_spans_and_counters():
                 "states_expanded": 24 * 3_628_800,
                 "frontier_states": 2 * 3_628_800}
     assert spanfold.metrics(program, counters, 2) == pytest.approx({
-        "driver.dispatch_idle_s_per_search": 155e-9,
+        # The one search's idle under bfs.level, dispatch, sync, search.
+        "driver.round_trip_idle_s_per_search": 460e-9,
         "driver.init_s_per_search": 45e-9,
         "level.frontier_share": 100 / 12,
         "level.expand_s_per_search": 185e-9,
         "level.copy_s_per_search": 30e-9})
+
+
+def _searches_idle(*idle_s):
+    """A fold of searches whose device-idle seconds by span are given."""
+    return {"span_s": {"bfs.level": 1.0}, "idle_by_span": {},
+            "device_by_scope": {},
+            "per_search": [{"seconds": 3.0, "span_s": {}, "idle_s": i}
+                           for i in idle_s]}
+
+
+@pytest.mark.parametrize("idle_s, want", [
+    # The same round trips, put under dispatch or under sync by the clocks.
+    ([{"bfs.dispatch": 0.01, "bfs.sync": 0.05, "bfs.init": 0.004}] * 3,
+     0.06),
+    ([{"bfs.sync": 0.06, "bfs.init": 0.004}] * 3, 0.06),
+    # One search with a stall in its sync is not the median one.
+    ([{"bfs.sync": 0.05, "bfs.level": 0.01}, {"bfs.sync": 0.65},
+      {"bfs.sync": 0.048, "bfs.search": 0.013}], 0.061),
+])
+def test_round_trip_idle_is_the_median_search_over_every_level_span(
+        idle_s, want):
+    got = spanfold.metrics(_searches_idle(*idle_s), {}, len(idle_s))
+    assert got == pytest.approx(
+        {"driver.round_trip_idle_s_per_search": want})
 
 
 def test_metrics_leave_out_what_the_trace_lacks():
@@ -161,11 +189,11 @@ def test_metrics_leave_out_what_the_trace_lacks():
 # ------------------------------------------------------------ whole runs
 
 def report(spec, bench, cell, seed=3, log=None):
-    return spanreport.report(spec, cell, seed, 0.01, t_start=0.0,
-                             log=log or io.StringIO(),
-                             bench_dir=bench,
-                             trace_dir=os.path.join(bench, "trace"),
-                             chip_check=cpu_device)
+    """One traced run of a cell at n=5 on the CPU."""
+    return harness.run_cell(spec, cell, seed, 0.01, True, t_start=0.0,
+                            log=log or io.StringIO(), bench_dir=bench,
+                            trace_dir=os.path.join(bench, "trace"),
+                            chip_check=cpu_device)
 
 
 @pytest.mark.parametrize("cell, n_calls", [("pancake-10.search", 6),
@@ -173,25 +201,30 @@ def report(spec, bench, cell, seed=3, log=None):
 def test_report_reads_the_program_spans(small_bench, cell, n_calls):
     spec, bench = small_bench
     log = io.StringIO()
-    out = report(spec, bench, cell, log=log)
-    assert out["run"]["correct"] is True
-    got = out["program"]["metrics"]
+    with obs.scope() as counted:
+        out = report(spec, bench, cell, log=log)
+    assert out["correct"] is True
+    assert set(PROGRAM_METRICS) <= {
+        m["name"] for m in harness.cell_metrics(spec, cell, True)}
+    got = {k: m["value"] for k, m in out["metrics"].items()}
     # n=5: each level call expands 120 states padded to 128; a search's
     # frontiers sum to 5! over its level calls.
     assert got["level.frontier_share"] == pytest.approx(
         100 * 120 / (n_calls * 128))
-    counters = out["program"]["counters"]
-    assert counters["searches"] == out["run"]["attempted"]
-    assert counters["level_calls"] == n_calls * out["run"]["attempted"]
+    # The set-up search, cut to one level call, then the window's.
+    counters = counted.delta()["implicit"]
+    assert counters["searches"] == out["attempted"] + 1
+    assert counters["level_calls"] == n_calls * out["attempted"] + 1
     assert got["driver.init_s_per_search"] > 0
-    assert got["driver.dispatch_idle_s_per_search"] > 0
-    idle = dict(out["program"]["idle_by_span"])
+    assert got["driver.round_trip_idle_s_per_search"] > 0
+    idle = dict(out["breakdown"]["idle_by_span"])
     assert {"bfs.init", "bfs.dispatch", "bfs.sync"} <= set(idle)
-    assert [row[:2] for row in out["program"]["levels"]][:2] == [[1, 1],
-                                                                 [2, 4]]
+    levels = ast.literal_eval(log.getvalue().split(
+        "levels of the first search (level, frontier, device s, host s): "
+    )[1].splitlines()[0])
+    assert [row[:2] for row in levels][:2] == [[1, 1], [2, 4]]
     assert obs.ACTIVE is False
-    assert log.getvalue().count("device idle under") == (
-        out["run"]["attempted"])
+    assert log.getvalue().count("device idle under") == out["attempted"]
     json.dumps(out)
 
 
@@ -208,7 +241,7 @@ def test_report_turns_the_spans_on_for_the_window_only(small_bench,
     monkeypatch.setattr(C, "implicit_bfs", watched)
     out = report(spec, bench, spec["workloads"][1]["name"])
     # The set-up search, cut to one level, then the window's searches.
-    assert seen == [(1, False)] + [(None, True)] * out["run"]["attempted"]
+    assert seen == [(1, False)] + [(None, True)] * out["attempted"]
     assert obs.ACTIVE is False
 
 
@@ -227,7 +260,8 @@ def test_report_turns_obs_off_when_the_window_raises(small_bench,
 
 
 @pytest.mark.parametrize("traced", [False, True])
-def test_benchmark_run_never_turns_obs_on(small_bench, monkeypatch, traced):
+def test_benchmark_run_turns_obs_on_only_in_a_traced_window(
+        small_bench, monkeypatch, traced):
     spec, bench = small_bench
     real = harness._searches
     seen = []
@@ -239,13 +273,20 @@ def test_benchmark_run_never_turns_obs_on(small_bench, monkeypatch, traced):
     monkeypatch.setattr(harness, "_searches", watched)
     result = harness.run_cell(spec, spec["workloads"][1]["name"], 3, 0.01,
                               traced, t_start=0.0, bench_dir=bench,
+                              log=io.StringIO(),
                               trace_dir=os.path.join(bench, "trace"),
                               chip_check=cpu_device)
-    assert seen == [False] and result["correct"] is True
+    assert seen == [traced] and result["correct"] is True
+    assert obs.ACTIVE is False
 
 
-def test_report_refuses_to_run_without_a_tpu(capsys):
-    assert jax.devices()[0].platform == "cpu"
-    assert spanreport.main(["--workload", "pancake-10.search", "--seed", "1",
-                            "--seconds", "1"]) == 1
-    assert "no TPU found" in capsys.readouterr().err
+def test_report_refuses_to_run_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join("perfbench", "run.py"), "--workload",
+         "pancake-10.search", "--seed", "1", "--seconds", "1", "--trace",
+         "1"], cwd=harness.ROOT, env=env, capture_output=True, text=True,
+        timeout=120)
+    assert proc.returncode == 1
+    assert "no TPU found" in proc.stderr
+    assert proc.stdout.strip() == ""

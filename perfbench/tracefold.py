@@ -165,7 +165,8 @@ def fold(trace: Dict[str, List[Event]], kernel_tag: str) -> Dict:
     The window runs from the start of the first search annotation to the
     end of the last.  Returns window and busy seconds, the self seconds of
     the kernels whose names hold ``kernel_tag`` and of all other
-    ops, the top ops, and the idle seconds by host activity."""
+    ops, the self seconds of every op (``op_s``) and the top ones, and the
+    idle seconds by host activity."""
     searches = [e for e in trace["host"] if e[0] == SEARCH_ANNOTATION]
     if not searches:
         raise ValueError("the trace holds no search annotation")
@@ -192,6 +193,7 @@ def fold(trace: Dict[str, List[Event]], kernel_tag: str) -> Dict:
         "kernel_s": kernel_ns / 1e9,
         "other_ops_s": (sum(per_op.values()) - kernel_ns) / 1e9,
         "n_kernel_events": sum(1 for e in ops if kernel_tag in e[0]),
+        "op_s": {k: v / 1e9 for k, v in per_op.items()},
         "device_ops": [[k, v / 1e9] for k, v in top],
         "idle_gaps": [[k, v / 1e9] for k, v in
                       sorted(idle.items(), key=lambda kv: -kv[1])[:10]],
