@@ -9,6 +9,26 @@ program searches with ``repro.core.constructs.implicit_bfs`` (Tier J, a
 configuration's ``"generators"``.  A Cayley graph looks the same from every
 vertex, so every start has the identity's level counts: the reference runs
 once and serves every start, and every search covers all ``n!`` states.
+
+One key of the configuration, and one file beside this one, serve the
+benchmark's tests alone; the harness reads neither:
+
+  "cpu"             the overrides that cut the configuration to a size the
+                    CPU tests run in seconds (here ``n`` and its
+                    ``generators``); the tests apply them with
+                    ``config.update(config["cpu"])``
+  cayley.faults.py  the faults of this search file, named as it is with
+                    ``faults`` for ``search``: its ``FAULTS`` maps a
+                    fault's name to ``(module, attribute, wrapper)``.  The
+                    tests replace ``module.attribute`` with ``wrapper(the
+                    original)`` for one whole run, and that run must come
+                    out not correct.  A wrapper receives the function of
+                    the timed path that it wraps and returns one that takes
+                    the same arguments; it must break the answer that path
+                    gives (a state, a batch, a count), not only its timing.
+                    Every faults file names ``unchanged_state``,
+                    ``half_the_batch`` and ``answer_altered``, and that of
+                    a search on more than one chip ``exchange_left_out``.
 """
 from __future__ import annotations
 

@@ -2,16 +2,14 @@
 """The control of a cell's ``correct``: the plain reference put in the
 program's place with its guarantee broken, which the check has to fail.
 
-    python3 perfbench/control.py --workload pancake-10.search --seeds 1 2 3
+    python3 perfbench/control.py --workload <cell> --seeds 1 2 3
 
 For each seed it asks the configuration's search (``harness.load_search``)
 for its control at the cell's size, from a start drawn from the seed, and
 compares the control's level counts with the reference's as a run does.
-For a Cayley graph the control is the reference with lost updates
-(``reference.level_counts(..., lost_updates=True)``: marks to states that
-share a packed word clobber each other).  It prints, per seed, each number
-compared beside its limit and whether the run would have been correct.
-The benchmark's own runs do not run it.
+What the control breaks is the search file's to say (its ``control``).
+It prints, per seed, each number compared beside its limit and whether
+the run would have been correct.  The benchmark's own runs do not run it.
 """
 from __future__ import annotations
 

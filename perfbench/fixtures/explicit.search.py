@@ -10,6 +10,16 @@ breadth-first search over adjacency lists built from the same edges.  A
 search covers the vertices it reaches, which depends on the start.  Each
 search books its levels in the ``explicit`` counters, which a per-layer
 reader of the fixture reads.
+
+As every configuration does, it states for the tests its ``"cpu"`` size
+(none to cut: 64 vertices), and its faults sit beside it in
+``explicit.faults.py``, whose ``FAULTS`` maps a fault's name to
+``(module, attribute, wrapper)``.  The tests replace ``module.attribute``
+(here ``constructs._bfs_level``, one RoomyList level) with ``wrapper(the
+original)`` for a whole run, which must then come out not correct: a
+wrapper takes the level's own arguments and breaks the frontier, the
+visited set or a count it returns, not only its timing.  See
+``cayley.search.py`` for the faults every such file names.
 """
 from __future__ import annotations
 

@@ -2,7 +2,11 @@
 
 The harness's look for a chip is replaced by one that hands it JAX's CPU
 device; everything else of a run is the harness's own.  ``small_bench``
-copies the benchmark's files with the graphs cut to a few thousand states.
+copies the benchmark's files with each configuration cut to the size its
+``"cpu"`` key states.  The tests of a cell are cases of each cell that
+``BENCHMARK.json`` lists, each with its own configuration's files; what
+holds only of a Cayley graph is tested for the configurations that name
+``cayley.search.py``.
 """
 from __future__ import annotations
 
@@ -22,6 +26,19 @@ from perfbench.control import control_readings
 
 ROOT = harness.ROOT
 FIXTURE = os.path.join(harness.BENCH_DIR, "fixtures", "trace_pancake8.json")
+SPEC = harness.load_spec(ROOT)
+CELLS = [c["name"] for c in SPEC["workloads"]]
+CPU_STATES = 10_000     # the most states a configuration's CPU size may have
+CAYLEY = "perfbench/configs/cayley.search.py"
+CAYLEY_CONFIGS = [c["name"] for c in SPEC["configs"]
+                  if harness.load_config(c["name"])["search"]["file"] == CAYLEY]
+# The members of a search object, as harness.py's docstring lists them.
+SEARCH_MEMBERS = ("n_states", "fanout", "scopes", "draw_start", "run",
+                  "states", "reference", "control")
+# The faults every faults file names; on more than one chip, also the
+# exchange between chips left out.
+FAULT_NAMES = {"unchanged_state", "half_the_batch", "answer_altered"}
+MULTICHIP_FAULT = "exchange_left_out"
 GENERATORS = {"pancake": reference.prefix_reversals,
               "bubblesort": reference.adjacent_transpositions}
 KNOWN_PANCAKE = {   # OEIS A067607 rows; n=9 equals Tier D's counts
@@ -47,19 +64,38 @@ def cpu_device(chips):
     return jax.devices()[0]
 
 
+def cell_config(cell: str):
+    """The configuration that a cell of ``BENCHMARK.json`` runs."""
+    return harness.load_config(harness.find_cell(SPEC, cell)["config"])
+
+
+def cpu_size(config):
+    """The configuration cut to the size its ``"cpu"`` key states."""
+    return {**config, **config["cpu"]}
+
+
+def load_faults(config, root=ROOT):
+    """A configuration's faults, from the file beside its search file
+    (``<stem>.faults.py`` for ``<stem>.search.py``): name -> (module,
+    attribute, wrapper), planted as ``module.attribute = wrapper(the
+    original)``."""
+    search = config["search"]["file"]
+    assert search.endswith(".search.py"), search
+    path = os.path.join(root, search[:-len(".search.py")] + ".faults.py")
+    return harness.load_file(path, "FAULTS")
+
+
 @pytest.fixture
 def small_bench(tmp_path):
-    """The benchmark's files with each graph cut to n=5, as (spec, dir)."""
+    """The benchmark's files with each configuration cut to its CPU size,
+    as (spec, dir)."""
     bench = tmp_path / "perfbench"
     shutil.copytree(harness.BENCH_DIR, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "test_*"))
     spec = harness.load_spec(ROOT)
     for c in spec["configs"]:
-        path = bench / "configs" / f"{c['name']}.json"
-        cfg = json.loads(path.read_text())
-        cfg["n"] = 5
-        cfg["generators"] = GENERATORS[c["name"].split("-")[0]](5)
-        path.write_text(json.dumps(cfg))
+        path = tmp_path / c["file"]
+        path.write_text(json.dumps(cpu_size(json.loads(path.read_text()))))
     peaks = json.loads((bench / "peaks.json").read_text())
     peaks["devices"][jax.devices()[0].device_kind] = {"hbm_bytes_per_s": 1e9}
     (bench / "peaks.json").write_text(json.dumps(peaks))
@@ -75,30 +111,55 @@ def run(spec, bench, cell, traced=False, seed=3):
 
 # ------------------------------------------------------------ discovery
 
-def test_discovery_finds_every_named_file():
-    spec = harness.load_spec(ROOT)
-    for c in spec["configs"]:
-        cfg = harness.load_config(c["name"])
-        assert os.path.join(ROOT, c["file"]) == os.path.join(
-            harness.BENCH_DIR, "configs", f"{c['name']}.json")
-        assert len(cfg["generators"]) == cfg["n"] - 1
-        assert callable(harness.load_file(os.path.join(
-            ROOT, cfg["rule"]["file"]), cfg["rule"]["function"]))
-        assert cfg["search"] == {"file": "perfbench/configs/cayley.search.py",
-                                 "function": "Cayley"}
-        graph = harness.load_search(cfg)
-        assert graph.n_states == math.factorial(cfg["n"])
-        assert graph.fanout == cfg["n"] - 1
-    for cell in spec["workloads"]:
-        assert harness.load_traffic(cell["name"])["traffic"] == cell["traffic"]
-        for traced in (False, True):
-            for m in harness.cell_metrics(spec, cell["name"], traced):
-                assert callable(harness.load_metric(m["name"]))
+@pytest.mark.parametrize("cell", CELLS)
+def test_discovery_finds_every_named_file(cell):
+    entry = harness.find_cell(SPEC, cell)
+    (c,) = [c for c in SPEC["configs"] if c["name"] == entry["config"]]
+    assert os.path.join(ROOT, c["file"]) == os.path.join(
+        harness.BENCH_DIR, "configs", f"{c['name']}.json")
+    cfg = harness.load_config(c["name"])
+    assert set(cfg["search"]) == {"file", "function"}
+    graph = harness.load_search(cfg)
+    small = harness.load_search(cpu_size(cfg))
+    for member in SEARCH_MEMBERS:
+        assert hasattr(graph, member) and hasattr(small, member), member
+    assert small.n_states <= min(graph.n_states, CPU_STATES)
+    faults = load_faults(cfg)
+    assert FAULT_NAMES <= set(faults)
+    assert entry["chips"] == 1 or MULTICHIP_FAULT in faults
+    for module, attr, wrap in faults.values():
+        assert callable(getattr(module, attr)) and callable(wrap)
+    assert harness.load_traffic(cell)["traffic"] == entry["traffic"]
+    for traced in (False, True):
+        metrics = harness.cell_metrics(SPEC, cell, traced)
+        assert metrics
+        for m in metrics:
+            assert callable(harness.load_metric(m["name"]))
+
+
+@pytest.mark.parametrize("name", CAYLEY_CONFIGS)
+def test_a_cayley_configuration_is_its_graph_at_both_sizes(name):
+    """Its generators are the family's, its CPU size the n=5 graph that
+    the tests' pinned numbers were taken at."""
+    cfg = harness.load_config(name)
+    family = GENERATORS[name.split("-")[0]]
+    assert callable(harness.load_file(os.path.join(
+        ROOT, cfg["rule"]["file"]), cfg["rule"]["function"]))
+    assert cfg["search"] == {"file": CAYLEY, "function": "Cayley"}
+    assert cfg["cpu"] == {"n": 5, "generators": family(5)}
+    for size in (cfg, cpu_size(cfg)):
+        assert size["generators"] == family(size["n"])
+        graph = harness.load_search(size)
+        assert graph.n_states == math.factorial(size["n"])
+        assert graph.fanout == size["n"] - 1
+
+
+def test_discovery_refuses_unknown_names():
     assert harness.load_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError, match="not in peaks.json"):
         harness.load_peaks("TPU v0")
     with pytest.raises(KeyError, match="no workload"):
-        harness.find_cell(spec, "no-such.cell")
+        harness.find_cell(SPEC, "no-such.cell")
 
 
 def test_cell_metrics_follow_the_workloads_key():
@@ -182,7 +243,7 @@ def test_fold_nesting_gaps_and_labels():
 @pytest.mark.parametrize("traced", [False, True])
 def test_last_line_schema(small_bench, traced):
     spec, bench = small_bench
-    cell = spec["workloads"][0]["name"]
+    cell = "pancake-10.search"
     result = run(spec, bench, cell, traced)
     assert list(result)[:5] == ["correct", "attempted", "failed", "metrics",
                                 "device"]

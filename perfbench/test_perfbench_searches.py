@@ -1,11 +1,13 @@
 """Tests of the configurations' search files, on the CPU.
 
 ``cayley.search.py`` serves both cells; its starts are pinned to those the
-harness drew before the file existed.  ``explicit_bench`` builds a
+harness drew before the file existed.  ``add_explicit`` adds a
 configuration that is not a Cayley graph (``fixtures/explicit.search.py``:
-an explicit graph searched by the RoomyList engine) with a per-layer
-reader of its own, as files beside the benchmark's, and runs it through
-the harness unchanged.
+an explicit graph searched by the RoomyList engine), with its faults file
+and a per-layer reader of its own, as new files beside the benchmark's;
+``explicit_bench`` runs it through the harness unchanged, and
+``test_a_configuration_joins_as_new_files`` shows that the per-cell tests
+take it as they find it.
 """
 from __future__ import annotations
 
@@ -13,17 +15,25 @@ import io
 import json
 import os
 import shutil
+import subprocess
+import sys
+import xml.etree.ElementTree as ET
 
 import jax
 import numpy as np
 import pytest
 
 from perfbench import harness, reference
-from perfbench.test_perfbench_harness import cpu_device, small_bench  # noqa: F401
+from perfbench.test_perfbench_harness import (CAYLEY_CONFIGS,  # noqa: F401
+                                              cpu_device, small_bench)
 
 FIXTURES = os.path.join(harness.BENCH_DIR, "fixtures")
 CELL = "explicit-64.search"
 COUNTER_METRIC = "explicit.levels_per_search"
+PER_CELL_TESTS = ("test_discovery_finds_every_named_file",
+                  "test_a_broken_timed_path_is_not_correct",
+                  "test_the_sound_path_is_correct",
+                  "test_the_control_is_not_correct")
 
 
 def explicit_graph(seed=5):
@@ -38,38 +48,55 @@ def explicit_graph(seed=5):
     return {"name": "explicit-64", "n_vertices": 64,
             "edges": sorted([u, v] for u, v in edges),
             "search": {"file": "perfbench/configs/explicit.search.py",
-                       "function": "Explicit"}}
+                       "function": "Explicit"},
+            "cpu": {}}
+
+
+def add_explicit(bench: str):
+    """Add the fixture's configuration and cell to the benchmark directory
+    ``bench`` by new files alone: the configuration, its search and faults
+    files, its traffic and its counter reader.  Returns the entries that
+    ``BENCHMARK.json`` gains under ``configs``, ``workloads`` and
+    ``per_layer``."""
+    for name in ("explicit.search.py", "explicit.faults.py"):
+        shutil.copy(os.path.join(FIXTURES, name),
+                    os.path.join(bench, "configs"))
+    shutil.copy(os.path.join(FIXTURES, f"{COUNTER_METRIC}.py"),
+                os.path.join(bench, "metrics"))
+    with open(os.path.join(bench, "configs", "explicit-64.json"), "w") as fh:
+        json.dump(explicit_graph(), fh)
+    with open(os.path.join(bench, "workloads", f"{CELL}.json"), "w") as fh:
+        json.dump({"traffic": "search",
+                   "why": "whole searches from random starts",
+                   "limits": {"worst_level_gap": 0}}, fh)
+    config = {"name": "explicit-64", "source": "a fixture of the tests",
+              "file": "perfbench/configs/explicit-64.json", "reduced": [],
+              "why": "an explicit graph searched by the RoomyList engine"}
+    cell = {"name": CELL, "config": "explicit-64", "traffic": "search",
+            "chips": 1, "why": "whole searches from random starts"}
+    metric = {"name": COUNTER_METRIC, "unit": "1", "better": "lower",
+              "source": "program_counter", "layer": "search driver",
+              "moves": "states_per_s", "workloads": [CELL]}
+    return config, cell, metric
 
 
 @pytest.fixture
 def explicit_bench(tmp_path):
     """(spec, bench dir) of one cell over ``explicit_graph``: the
-    benchmark's files, with the fixture's search file, its counter reader,
-    configuration and traffic added beside them."""
+    benchmark's files, with the fixture's added beside them."""
     bench = tmp_path / "perfbench"
     shutil.copytree(harness.BENCH_DIR, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "test_*"))
-    shutil.copy(os.path.join(FIXTURES, "explicit.search.py"),
-                bench / "configs")
-    shutil.copy(os.path.join(FIXTURES, f"{COUNTER_METRIC}.py"),
-                bench / "metrics")
-    (bench / "configs" / "explicit-64.json").write_text(
-        json.dumps(explicit_graph()))
-    (bench / "workloads" / f"{CELL}.json").write_text(json.dumps(
-        {"traffic": "search", "why": "whole searches from random starts",
-         "limits": {"worst_level_gap": 0}}))
+    config, cell, metric = add_explicit(str(bench))
     peaks = json.loads((bench / "peaks.json").read_text())
     peaks["devices"][jax.devices()[0].device_kind] = {"hbm_bytes_per_s": 1e9}
     (bench / "peaks.json").write_text(json.dumps(peaks))
     spec = {
-        "configs": [{"name": "explicit-64"}],
-        "workloads": [{"name": CELL, "config": "explicit-64",
-                       "traffic": "search", "chips": 1}],
+        "configs": [config],
+        "workloads": [cell],
         "end_to_end": [{"name": "states_per_s", "unit": "states/s"},
                        {"name": "setup_s", "unit": "s"}],
-        "per_layer": [{"name": COUNTER_METRIC, "unit": "1",
-                       "workloads": [CELL]},
-                      {"name": "device.idle_share", "unit": "%"}],
+        "per_layer": [metric, {"name": "device.idle_share", "unit": "%"}],
     }
     return spec, str(bench)
 
@@ -160,12 +187,45 @@ def test_cayley_starts_are_the_ones_drawn_before(small_bench):
         assert [graph.draw_start(rng) for _ in starts] == starts
         assert graph.states([1]) == graph.n_states
     spec, bench = small_bench
-    for c in spec["configs"]:
-        graph = harness.load_search(harness.load_config(c["name"], bench),
-                                    bench)
+    for name in CAYLEY_CONFIGS:
+        graph = harness.load_search(harness.load_config(name, bench), bench)
         got, want_levels = graph.control(np.random.default_rng(11))
-        start = np.random.default_rng(11).permutation(5)
-        assert got == reference.level_counts(5, graph.generators,
+        start = np.random.default_rng(11).permutation(graph.n)
+        assert got == reference.level_counts(graph.n, graph.generators,
                                              lost_updates=True, start=start)
         assert want_levels == graph.reference(None) == (
-            reference.level_counts(5, graph.generators))
+            reference.level_counts(graph.n, graph.generators))
+
+
+def test_a_configuration_joins_as_new_files(tmp_path):
+    """The fixture joins a copy of the benchmark as one more configuration
+    and cell by new files and new entries alone; the copy's per-cell tests
+    then take it, as a subprocess on the CPU, and pass."""
+    root = tmp_path / "checkout"
+    shutil.copytree(harness.BENCH_DIR, root / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    spec = harness.load_spec(harness.ROOT)
+    config, cell, metric = add_explicit(str(root / "perfbench"))
+    assert all(p.read_bytes() == b for p, b in before.items())
+    spec["configs"].append(config)
+    spec["workloads"].append(cell)
+    spec["per_layer"].append(metric)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+    report = tmp_path / "report.xml"
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [str(root), os.path.join(harness.ROOT, "src")]))
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-k", "explicit-64 and not test_a_configuration_joins_as_new_files",
+         f"--junitxml={report}", "perfbench"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-6000:] + proc.stderr[-2000:]
+    passed = [case.get("name") for case in ET.parse(report).iter("testcase")
+              if not any(case.find(k) is not None
+                         for k in ("failure", "error", "skipped"))]
+    for test in PER_CELL_TESTS:
+        assert any(name.startswith(f"{test}[") and CELL in name
+                   for name in passed), (test, passed)

@@ -2,8 +2,8 @@
 (``spanfold.py``) and of what a traced run reports from them, on the CPU.
 
 The traced runs use ``small_bench`` of the harness's tests: the
-benchmark's files with each graph cut to n=5, and JAX's CPU device in
-place of the chip.
+benchmark's files with each configuration cut to its CPU size (n=5 for
+both Cayley graphs), and JAX's CPU device in place of the chip.
 """
 from __future__ import annotations
 
@@ -239,7 +239,7 @@ def test_report_turns_the_spans_on_for_the_window_only(small_bench,
         return real(*args, **kw)
 
     monkeypatch.setattr(C, "implicit_bfs", watched)
-    out = report(spec, bench, spec["workloads"][1]["name"])
+    out = report(spec, bench, "bubblesort-9.search")
     # The set-up search, cut to one level, then the window's searches.
     assert seen == [(1, False)] + [(None, True)] * out["attempted"]
     assert obs.ACTIVE is False
@@ -255,7 +255,7 @@ def test_report_turns_obs_off_when_the_window_raises(small_bench,
 
     monkeypatch.setattr(harness, "_searches", broken)
     with pytest.raises(RuntimeError, match="the window failed"):
-        report(spec, bench, spec["workloads"][0]["name"])
+        report(spec, bench, "pancake-10.search")
     assert obs.ACTIVE is False and obs._ANNOTATE is None
 
 
@@ -271,7 +271,7 @@ def test_benchmark_run_turns_obs_on_only_in_a_traced_window(
         return real(*args)
 
     monkeypatch.setattr(harness, "_searches", watched)
-    result = harness.run_cell(spec, spec["workloads"][1]["name"], 3, 0.01,
+    result = harness.run_cell(spec, "bubblesort-9.search", 3, 0.01,
                               traced, t_start=0.0, bench_dir=bench,
                               log=io.StringIO(),
                               trace_dir=os.path.join(bench, "trace"),
