@@ -15,10 +15,14 @@ is exactly VPU-shaped work:
                         whose 2-bit field must become ``mark`` iff it
                         currently holds ``only_if`` (the OR-style visited
                         test of the BFS — marks on non-UNSEEN states are
-                        absorbed).  Sequential read-modify-write per op
-                        on a VMEM-resident table; dropped ops go to a trash
-                        word.  The table must fit VMEM: on v5e (128 MiB)
-                        one of up to 120 MiB compiles, pancake n=12.
+                        absorbed).  Sequential read-modify-write per live
+                        op on a VMEM-resident table; dropped ops are aimed
+                        at a trash word and do no work: an op block with
+                        no live op is skipped whole, and a block with some
+                        first packs its live ops together, so a dead op
+                        costs a scalar load and store.  The table must
+                        fit VMEM: on v5e (128 MiB) one of up to 120 MiB
+                        compiles, pancake n=12.
 
   bitpack_mark_rotate_count
                         the two fused into ONE kernel — the whole per-level
@@ -46,6 +50,7 @@ wrappers.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +62,7 @@ FIELDS_PER_WORD = 16
 LANES = 128
 ROW_BLOCK = 512          # table rows per block (256 KiB of uint32)
 DEFAULT_BM = 2048        # scatter ops per SMEM block
+PACK_UNROLL = 16         # ops per loop step when packing live ops
 VMEM_HEADROOM = 16 << 20  # scoped VMEM beyond the table: rotate temporaries
 
 
@@ -72,9 +78,10 @@ def make_lut(table) -> int:
 def table_layout(n_words: int, row_block: int = ROW_BLOCK):
     """``(rows, rb)`` of the lane-dense ``(rows, 128)`` uint32 table all
     three array-pass kernels share.  It holds ``n_words + 1`` words — word
-    ``n_words`` is the trash word that absorbs dropped marks, so it lives
-    in the padding — rounded up to whole blocks of ``rb`` rows, ``rb`` a
-    multiple of the (8, 128) uint32 tile."""
+    ``n_words`` is the trash word that dropped marks are aimed at (the
+    mark kernels apply none of them), so it lives in the
+    padding — rounded up to whole blocks of ``rb`` rows, ``rb`` a multiple
+    of the (8, 128) uint32 tile."""
     need = -(-(n_words + 1) // LANES)
     rb = min(row_block, -(-need // 8) * 8)
     return -(-need // rb) * rb, rb
@@ -170,14 +177,13 @@ def bitpack_lut_count(
 
 # ------------------------------------- scatter mark (+ rotate + count)
 
-def _apply_marks(idx_ref, tab, *, mark: int, only_if: int):
-    """Apply one SMEM block of ops, one masked read-modify-write of a
-    table row each, in order.  Every index is in [0, cap]: cap is field 0
-    of the trash word."""
+def _mark_ops(ops_ref, n, tab, *, mark: int, only_if: int):
+    """Apply the first ``n`` ops of an SMEM ref in order, one masked
+    read-modify-write of a table row each."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     def body(i, carry):
-        elt = idx_ref[i]
+        elt = ops_ref[i]
         word = elt // FIELDS_PER_WORD
         row = word // LANES
         sh = (2 * (elt % FIELDS_PER_WORD)).astype(jnp.uint32)
@@ -187,20 +193,56 @@ def _apply_marks(idx_ref, tab, *, mark: int, only_if: int):
             hit, (w & ~(jnp.uint32(3) << sh)) | (jnp.uint32(mark) << sh), w)
         return carry
 
-    jax.lax.fori_loop(0, idx_ref.shape[0], body, 0)
+    jax.lax.fori_loop(0, n, body, 0)
 
 
-def _scatter_kernel(idx_ref, tab_hbm, out_hbm, *rest, mark: int,
-                    only_if: int, rotate):
-    """Grid over op blocks.  The table is copied into VMEM once, before the
-    first block, and back to HBM (the same buffer: input and output are
-    aliased) after the last.  With ``rotate = (lut, count_val, n_words,
-    rb)`` the last block also LUT-rotates and counts the resident table,
-    ``rb`` rows at a time, before the copy back."""
+def _pack_live(idx_ref, live_ref, *, cap: int):
+    """Copy the live ops of an SMEM block, in order, to the front of
+    ``live_ref``: each op is stored at the live count so far, which only a
+    live op advances, so no op branches.  The loop body takes PACK_UNROLL
+    ops (or what divides the block), to spread the loop's own cost."""
+    bm = idx_ref.shape[0]
+    u = math.gcd(bm, PACK_UNROLL)
+
+    def body(g, n):
+        for j in range(u):
+            elt = idx_ref[g * u + j]
+            live_ref[n] = elt
+            n = n + (elt != cap).astype(jnp.int32)
+        return n
+
+    jax.lax.fori_loop(0, bm // u, body, jnp.int32(0))
+
+
+def _apply_marks(idx_ref, idx_vec, live_ref, tab, *, cap: int, mark: int,
+                 only_if: int):
+    """Apply one block of ops in order.  Every index is in [0, cap]; an op
+    aimed at cap (field 0 of the trash word) is dead and is not applied.
+    The block's live count comes from its vector copy ``idx_vec`` in one
+    compare and sum.  A block with no live op does nothing; any other
+    first packs its live ops into ``live_ref`` (_pack_live), so a dead op
+    costs a scalar load and store and no read-modify-write."""
+    live = jnp.sum((idx_vec[...] != cap).astype(jnp.int32))
+
+    @pl.when(live > 0)
+    def _mark_live():
+        _pack_live(idx_ref, live_ref, cap=cap)
+        _mark_ops(live_ref, live, tab, mark=mark, only_if=only_if)
+
+
+def _scatter_kernel(idx_ref, idx_vec, tab_hbm, out_hbm, *rest, cap: int,
+                    mark: int, only_if: int, rotate):
+    """Grid over op blocks, each in SMEM (``idx_ref``) and in VMEM
+    (``idx_vec``, for the live count).  The table is copied into VMEM once,
+    before the first block, and back to HBM (the same buffer: input and
+    output are aliased) after the last, whether or not those blocks hold a
+    live op.  With ``rotate = (lut, count_val, n_words, rb)`` the last
+    block also LUT-rotates and counts the resident table, ``rb`` rows at a
+    time, before the copy back."""
     if rotate is None:
-        tab, sem = rest
+        live_ref, tab, sem = rest
     else:
-        cnt_ref, tab, sem = rest
+        cnt_ref, live_ref, tab, sem = rest
     blk = pl.program_id(0)
 
     @pl.when(blk == 0)
@@ -209,7 +251,8 @@ def _scatter_kernel(idx_ref, tab_hbm, out_hbm, *rest, mark: int,
         copy.start()
         copy.wait()
 
-    _apply_marks(idx_ref, tab, mark=mark, only_if=only_if)
+    _apply_marks(idx_ref, idx_vec, live_ref, tab, cap=cap, mark=mark,
+                 only_if=only_if)
 
     @pl.when(blk == pl.num_programs(0) - 1)
     def _store():
@@ -230,20 +273,29 @@ def _scatter_kernel(idx_ref, tab_hbm, out_hbm, *rest, mark: int,
         copy.wait()
 
 
-def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
-                  rotate_lut=None, count_val=None):
-    """Stage the table and the op indices and run _scatter_kernel.  Out-of
-    range and negative indices retarget the trash word; the indices stay
-    a flat int32 array read one SMEM block at a time."""
-    n_words = packed.shape[0]
-    rows, rb = table_layout(n_words)
-    cap = n_words * FIELDS_PER_WORD
+def _stage_ops(idx, cap: int, bm: int):
+    """The op indices as the mark kernel reads them: out-of-range and
+    negative indices, and the padding to whole blocks of ``bm``, retarget
+    ``cap``, field 0 of the trash word, so an op is dead exactly when its
+    index is ``cap``, and a block's live count is its ops below ``cap``."""
     m = idx.shape[0]
-    bm = min(block_m, max(m, 1))
     m_pad = -(-max(m, 1) // bm) * bm
     idx = idx.astype(jnp.int32)
     idx = jnp.where((idx >= 0) & (idx < cap), idx, cap)
-    idx = jnp.pad(idx, (0, m_pad - m), constant_values=cap)
+    return jnp.pad(idx, (0, m_pad - m), constant_values=cap)
+
+
+def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
+                  rotate_lut=None, count_val=None):
+    """Stage the table and the op indices (_stage_ops) and run
+    _scatter_kernel over the op blocks.  Dead ops do no work and are not
+    applied.  The indices stay a flat int32 array, read one block at a
+    time into SMEM and VMEM."""
+    n_words = packed.shape[0]
+    rows, rb = table_layout(n_words)
+    cap = n_words * FIELDS_PER_WORD
+    bm = min(block_m, max(idx.shape[0], 1))
+    idx = _stage_ops(idx, cap, bm)
 
     rotate = (None if rotate_lut is None
               else (rotate_lut, count_val, n_words, rb))
@@ -255,18 +307,20 @@ def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
         out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.int32))
     table_bytes = rows * LANES * 4
     res = pl.pallas_call(
-        functools.partial(_scatter_kernel, mark=mark, only_if=only_if,
-                          rotate=rotate),
-        grid=(m_pad // bm,),
+        functools.partial(_scatter_kernel, cap=cap, mark=mark,
+                          only_if=only_if, rotate=rotate),
+        grid=(idx.shape[0] // bm,),
         in_specs=[
             pl.BlockSpec((bm,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm,), lambda i: (i,)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.uint32),
+        scratch_shapes=[pltpu.SMEM((bm,), jnp.int32),
+                        pltpu.VMEM((rows, LANES), jnp.uint32),
                         pltpu.SemaphoreType.DMA(())],
-        input_output_aliases={1: 0},
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=table_bytes + VMEM_HEADROOM,
@@ -274,7 +328,7 @@ def _scatter_call(packed, idx, *, mark, only_if, block_m, interpret,
         interpret=interpret,
         name=("roomy_bitpack_scatter_mark" if rotate is None
               else "roomy_bitpack_mark_rotate_count"),
-    )(idx, _to_table(packed, rows))
+    )(idx, idx, _to_table(packed, rows))
     return (_from_table(res[0], n_words),
             res[1][0, 0] if rotate else None)
 
@@ -290,7 +344,10 @@ def bitpack_scatter_mark(
 ) -> jax.Array:
     """packed[idx] ← mark where the 2-bit field holds ``only_if`` (the
     delayed-mark apply of the implicit BFS).  Duplicate indices are safe —
-    the first mark wins and later ones see ``mark`` ≠ ``only_if``."""
+    the first mark wins and later ones see ``mark`` ≠ ``only_if``.  Out of
+    range and negative indices are aimed at the trash word: they are not
+    applied and cost no read-modify-write, and an op block of nothing else
+    is skipped."""
     out, _ = _scatter_call(packed, idx, mark=mark, only_if=only_if,
                            block_m=block_m, interpret=interpret)
     return out
@@ -316,7 +373,9 @@ def bitpack_mark_rotate_count(
     Returns (new_packed (W,) uint32, count () int32).
 
     Equivalent to bitpack_scatter_mark followed by bitpack_lut_count, but
-    the packed table crosses HBM once instead of twice per level.
+    the packed table crosses HBM once instead of twice per level.  Dropped
+    ops cost no read-modify-write, as there; the rotate and count run
+    whatever the ops.
     """
     return _scatter_call(packed, idx, mark=mark, only_if=only_if,
                          block_m=block_m, interpret=interpret,

@@ -143,7 +143,9 @@ def bitpack_lut_count(packed, lut, count_val, *, impl="auto",
 def bitpack_scatter_mark(packed, idx, *, mark=2, only_if=0, impl="auto",
                          block_m=_bp.DEFAULT_BM):
     """packed[idx]'s 2-bit field ← mark where it currently holds only_if;
-    out-of-range indices dropped, duplicates safe (first mark wins)."""
+    duplicates safe (first mark wins).  Out-of-range and negative indices
+    are aimed at the trash word: the kernel applies none of them, does no
+    read-modify-write for them, and skips an op block of nothing else."""
     mode = _resolve(impl)
     if mode in ("pallas", "interpret"):
         return _bp.bitpack_scatter_mark(packed, idx, mark=mark,
@@ -160,7 +162,9 @@ def bitpack_mark_rotate_count(packed, idx, lut, count_val, *, mark=2,
     """Fused scatter-mark + lut-rotate + count — the implicit BFS's whole
     per-level array pass in one kernel (one HBM traversal of the packed
     words instead of two).  Semantics are exactly bitpack_scatter_mark
-    followed by bitpack_lut_count; the count covers ALL W·16 fields."""
+    followed by bitpack_lut_count; the count covers ALL W·16 fields.
+    Dropped ops are not applied and cost no read-modify-write, as in
+    bitpack_scatter_mark; the rotate and count run whatever the ops."""
     mode = _resolve(impl)
     if mode in ("pallas", "interpret"):
         return _bp.bitpack_mark_rotate_count(

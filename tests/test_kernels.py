@@ -167,6 +167,39 @@ BITPACK_CASES = [
 ]
 
 
+# The mark kernels' dead-op skip: a table of DEAD_W words, op blocks of
+# DEAD_BM, each case a list of blocks, "L" all live, "D" all dead (the trash
+# index, out of range or negative), "E" dead but for its first and last op.
+DEAD_W, DEAD_BM = 40, 8
+DEAD_MARK_CASES = {
+    "all_trash": "DDDD",
+    "all_live": "LLLL",
+    "dead_blocks_between_live": "DLDDLDL",
+    "live_across_block_edges": "EEE",
+    "dead_last_block": "LLD",
+    "ragged_tail": "DLD+",     # "+": a tail of 3 ops, one of them live
+}
+
+
+def dead_mark_ops(case):
+    """The op indices of a DEAD_MARK_CASES case, with duplicates."""
+    rng = np.random.default_rng(sorted(DEAD_MARK_CASES).index(case))
+    cap = DEAD_W * 16
+    dead = np.array([cap, cap + 5, -1, -7, cap])
+    blocks = []
+    for kind in DEAD_MARK_CASES[case]:
+        n = 3 if kind == "+" else DEAD_BM
+        ops_ = rng.choice(dead, n)
+        if kind == "L":
+            ops_ = rng.integers(0, cap, n)
+        elif kind == "E":
+            ops_[[0, -1]] = rng.integers(0, cap, 2)
+        elif kind == "+":
+            ops_[1] = rng.integers(0, cap)
+        blocks.append(ops_)
+    return np.concatenate(blocks).astype(np.int32)
+
+
 class TestBitpack:
     """2-bit packed-array kernels (the implicit-BFS hot paths) vs oracles."""
 
@@ -234,6 +267,45 @@ class TestBitpack:
                                                        2, 0)
         assert np.array_equal(np.asarray(got), np.asarray(want))
         assert int(gcnt) == int(wcnt)
+
+    # ------------------------------------------- dead marks skip their work
+
+    @pytest.mark.parametrize("kernel",
+                             ["scatter_mark", "mark_rotate_count", "staging"])
+    @pytest.mark.parametrize("case", sorted(DEAD_MARK_CASES))
+    def test_dead_marks_match_ref(self, kernel, case):
+        # Ops aimed at the trash word are skipped, a whole op block at a
+        # time where the block has no live op; the live ones still land.
+        # The "staging" cases check what makes an op dead: staged, its
+        # index is cap exactly when it was out of range or negative, or
+        # padding to whole blocks.
+        packed = jax.random.randint(jax.random.PRNGKey(5), (DEAD_W,), 0,
+                                    1 << 30, dtype=jnp.int32).astype(jnp.uint32)
+        idx = jnp.asarray(dead_mark_ops(case))
+        lut = 0 | (3 << 2) | (1 << 4) | (3 << 6)    # the BFS rotate LUT
+        if kernel == "staging":
+            from repro.kernels import bitpack as bp
+            cap = DEAD_W * 16
+            raw = np.asarray(idx)
+            staged = np.asarray(bp._stage_ops(idx, cap, DEAD_BM))
+            assert staged.shape == (-(-raw.size // DEAD_BM) * DEAD_BM,)
+            assert (staged[raw.size:] == cap).all()
+            ok = (raw >= 0) & (raw < cap)
+            assert np.array_equal(staged[:raw.size], np.where(ok, raw, cap))
+            return
+        if kernel == "scatter_mark":
+            got = ops.bitpack_scatter_mark(packed, idx, impl="interpret",
+                                           block_m=DEAD_BM)
+            want = ref.bitpack_scatter_mark_ref(packed, idx, 2, 0)
+        else:
+            got, gcnt = ops.bitpack_mark_rotate_count(
+                packed, idx, lut, 1, impl="interpret", block_m=DEAD_BM)
+            want, wcnt = ref.bitpack_mark_rotate_count_ref(packed, idx, lut,
+                                                           1, 2, 0)
+            assert int(gcnt) == int(wcnt)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        if case == "all_trash" and kernel == "scatter_mark":
+            assert np.array_equal(np.asarray(got), np.asarray(packed))
 
     # ------------------------------------------ serving-tier gather path
 
